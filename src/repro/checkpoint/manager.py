@@ -139,6 +139,9 @@ class CheckpointManager:
                 rt.wait_on(fut)
             self._in_flight = None
 
+        # device_get returns only once every leaf is on the host, so the
+        # snapshot is complete before the caller's next step donates (and
+        # overwrites) the device buffers it was read from
         host_leaves = [(k, np.asarray(jax.device_get(v)))
                        for k, v in flatten_with_paths(tree)]
         step_dir = self.dir / f"step_{step:08d}"

@@ -104,7 +104,10 @@ def mesh_context(mesh: Mesh, rules: dict | None = None):
     _ctx.mesh = mesh
     _ctx.rules = {**LOGICAL_RULES, **(rules or {})}
     try:
-        with mesh:  # classic Mesh context (shard_map gets mesh explicitly)
+        # the plain Mesh context, not jax.set_mesh: under set_mesh, eager ops
+        # re-express their outputs on the context mesh and fail for arrays
+        # placed on another mesh (elastic restore compares both)
+        with mesh:
             yield mesh
     finally:
         _ctx.mesh, _ctx.rules = prev_mesh, prev_rules

@@ -24,7 +24,6 @@ from pathlib import Path
 
 import jax
 
-from ..compat import cost_analysis_dict
 from ..configs import ARCHS, SHAPES, cell_supported, get_config
 from ..distributed import mesh_context
 from ..launch.mesh import make_production_mesh
@@ -193,7 +192,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, force: bool = False,
             t_lower = time.time() - t0
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
-            cost = cost_analysis_dict(compiled) or {}
+            cost = compiled.cost_analysis() or {}
             cost = {k: float(v) for k, v in cost.items()
                     if isinstance(v, (int, float))
                     and "utilization" not in k}

@@ -13,7 +13,6 @@ import argparse
 import json
 import sys
 import time
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +22,7 @@ from ..configs import ARCHS, get_config, get_smoke_config
 from ..core import Cluster, IORuntime, RealBackend, StorageDevice, WorkerNode, io, task
 from ..models import Model
 from ..obs.report import percentile, span_latencies
+from .compile_cache import configure_compile_cache
 
 
 @io
@@ -111,6 +111,7 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--trace", default=None)
     args = ap.parse_args(argv)
+    configure_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     out = serve(cfg, n_requests=args.requests, prompt_len=args.prompt_len,
                 max_new=args.max_new, batch=args.batch, trace_path=args.trace)

@@ -13,25 +13,23 @@ the paper's non-I/O-aware baseline).
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import signal
 import sys
 import time
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..checkpoint import CheckpointManager
 from ..configs import ARCHS, get_config, get_smoke_config
 from ..configs.base import ModelConfig
 from ..core import Cluster, IORuntime, RealBackend, StorageDevice, WorkerNode
 from ..data import PrefetchLoader, SyntheticCorpus
-from ..distributed import mesh_context
 from ..models import Model
 from ..optim import AdamWConfig, adamw_init, adamw_update
-from .mesh import make_local_mesh
+from .compile_cache import configure_compile_cache
 
 PRESETS = {
     # ~100M-class model for real-hardware runs; smaller ones for CPU demos
@@ -57,6 +55,19 @@ def build_cluster(io_executors: int = 8, device_bw: float = 2000.0):
                                        storage=dev)])
 
 
+def make_train_step(model: Model, opt: AdamWConfig):
+    """The jitted step (params, opt_state, batch) -> (params, opt_state,
+    loss, gnorm). Params and optimizer state are donated: the step writes
+    the new ones into the old buffers, so one copy of the train state fits
+    the device."""
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def train_step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(model.loss)(params, batch)
+        new_p, new_o, gnorm = adamw_update(grads, params, opt_state, opt)
+        return new_p, new_o, loss, gnorm
+    return train_step
+
+
 def train(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
           ckpt_dir: str | None, ckpt_every: int, io_aware: bool = True,
           resume: bool = True, log_path: str | None = None,
@@ -67,11 +78,7 @@ def train(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     opt_state = adamw_init(params)
     corpus = SyntheticCorpus(cfg.vocab_size, seq, batch, seed=seed)
 
-    @jax.jit
-    def train_step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(model.loss)(params, batch)
-        new_p, new_o, gnorm = adamw_update(grads, params, opt_state, opt)
-        return new_p, new_o, loss, gnorm
+    train_step = make_train_step(model, opt)
 
     mgr = CheckpointManager(ckpt_dir, n_shards=8) if ckpt_dir else None
     start_step = 0
@@ -88,14 +95,17 @@ def train(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
 
     log_f = open(log_path, "a") if log_path else None
     cluster = build_cluster()
-    losses = []
+    losses, step_s, save_stall_s = [], [], []
     t_start = time.monotonic()
     with IORuntime(cluster, backend=RealBackend()) as rt:
         loader = PrefetchLoader(corpus, depth=2) if io_aware else None
         for step in range(start_step, steps):
             b = loader.get(step) if io_aware else corpus.batch(step)
             b = {k: jnp.asarray(v) for k, v in b.items()}
+            t0 = time.perf_counter()
             params, opt_state, loss, gnorm = train_step(params, opt_state, b)
+            jax.block_until_ready((params, opt_state, loss))
+            step_s.append(time.perf_counter() - t0)
             losses.append(float(loss))
             if log_f:
                 log_f.write(json.dumps({"step": step, "loss": float(loss),
@@ -103,7 +113,9 @@ def train(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
                                         "t": time.monotonic() - t_start}) + "\n")
                 log_f.flush()
             if mgr and ckpt_every and (step + 1) % ckpt_every == 0:
+                t0 = time.perf_counter()
                 mgr.save(step, (params, opt_state), sync=not io_aware)
+                save_stall_s.append(time.perf_counter() - t0)
             if stop["now"]:
                 print(f"[train] SIGTERM at step {step}: final sync save",
                       flush=True)
@@ -117,6 +129,8 @@ def train(cfg: ModelConfig, *, steps: int, batch: int, seq: int,
     if log_f:
         log_f.close()
     return {"losses": losses, "steps_run": len(losses),
+            "start_step": start_step, "step_s": step_s,
+            "save_stall_s": save_stall_s,
             "final_loss": losses[-1] if losses else None,
             "runtime_stats": {k: v for k, v in stats.items()
                               if k not in ("tuners",)},
@@ -139,6 +153,7 @@ def main(argv=None):
     ap.add_argument("--no-resume", action="store_true")
     ap.add_argument("--log", default=None)
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     if args.arch:
         cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)

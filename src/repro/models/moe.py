@@ -10,13 +10,11 @@ function runs unpartitioned.
 from __future__ import annotations
 
 import math
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map_no_check
 from ..distributed import current_mesh, batch_axes
 from ..distributed.sharding import current_rules
 from .layers import _init, mlp_init, mlp_apply
@@ -122,10 +120,10 @@ def moe_apply(p, x, *, n_top: int, capacity_factor: float = 1.25,
             return yl.reshape(Bl, Sl, Dl), aux
 
         pw = {k: p[k] for k in ("router", "gate", "up", "down")}
-        y, aux = shard_map_no_check(
+        y, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(wspec, xspec),
-            out_specs=(xspec, P()),
+            out_specs=(xspec, P()), check_vma=False,
         )(pw, x)
     if "shared" in p:
         y = y + mlp_apply(p["shared"], x)

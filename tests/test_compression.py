@@ -2,12 +2,10 @@
 numerics vs the exact path."""
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 from hypothesis_support import given, settings, st
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 from repro.optim.compression import (compressed_grads, dequantize_int8,
                                      quantize_int8)
@@ -41,8 +39,8 @@ def test_compressed_psum_matches_mean():
     def f(g):
         return compressed_grads({"w": g}, "data")["w"]
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
-                            out_specs=P("data")))(g)
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                                out_specs=P("data")))(g)
     # single-host mean == identity up to quantisation error
     rel = float(jnp.max(jnp.abs(out - g)) / jnp.max(jnp.abs(g)))
     assert rel < 1e-2
@@ -72,8 +70,8 @@ def test_dp_step_with_compression_close_to_exact():
 
     def reduce_fn(g):
         return compressed_grads(g, "data")
-    gq = jax.jit(shard_map(reduce_fn, mesh=mesh,
-                           in_specs=P(), out_specs=P()))(grads)
+    gq = jax.jit(jax.shard_map(reduce_fn, mesh=mesh,
+                               in_specs=P(), out_specs=P()))(grads)
     p_comp, _, _ = adamw_update(gq, params, opt, acfg)
     deltas = jax.tree.map(
         lambda a, b: float(jnp.max(jnp.abs(a.astype(jnp.float32) -

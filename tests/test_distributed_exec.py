@@ -23,6 +23,7 @@ import numpy as np
 from repro.configs import get_smoke_config
 from repro.distributed import mesh_context
 from repro.distributed.sharding import STRATEGIES
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import build_cell, model_shapes_and_axes, tree_shardings, with_shardings
 from repro.models import Model
 from repro.optim import AdamWConfig, adamw_init, adamw_update
@@ -45,7 +46,7 @@ def step(p, o, b):
 loss1, p1 = jax.jit(step)(params, opt, batch)
 
 # 4x2 mesh, tp_fsdp rules, actually executed
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 with mesh_context(mesh, rules=STRATEGIES["tp_fsdp"]):
     sh = tree_shardings(jax.eval_shape(lambda: params), axes, mesh)
     p_sharded = jax.tree.map(jax.device_put, params, sh)
@@ -66,6 +67,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_smoke_config
 from repro.distributed import mesh_context
 from repro.distributed.sharding import STRATEGIES
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import tree_shardings
 from repro.models import Model
 
@@ -74,7 +76,7 @@ cfg = get_smoke_config("smollm-360m").replace(dtype=jnp.float32)
 model = Model(cfg)
 params, axes = model.init(jax.random.PRNGKey(0))
 
-mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+mesh_a = make_mesh((4, 2), ("data", "model"))
 with mesh_context(mesh_a, rules=STRATEGIES["tp_fsdp"]):
     sh_a = tree_shardings(jax.eval_shape(lambda: params), axes, mesh_a)
     p_a = jax.tree.map(jax.device_put, params, sh_a)
@@ -82,7 +84,7 @@ with mesh_context(mesh_a, rules=STRATEGIES["tp_fsdp"]):
     mgr.save(3, p_a, sync=True)
 
 # relaunch onto a DIFFERENT mesh shape: (2, 4)
-mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+mesh_b = make_mesh((2, 4), ("data", "model"))
 with mesh_context(mesh_b, rules=STRATEGIES["tp_fsdp"]):
     sh_b = tree_shardings(jax.eval_shape(lambda: params), axes, mesh_b)
     p_b, step = mgr.restore(params, shardings=sh_b)

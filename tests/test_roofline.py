@@ -4,10 +4,8 @@ uses an analytic FLOPs model — validated here against XLA on a small
 UNROLLED config where XLA's count is complete."""
 import jax
 import jax.numpy as jnp
-import pytest
 
 from benchmarks.analytic import cell_cost, roofline_terms
-from repro.compat import cost_analysis_dict
 from repro.configs.base import ModelConfig, ShapeCell
 from repro.models import Model
 from repro.optim import AdamWConfig, adamw_init, adamw_update
@@ -29,7 +27,7 @@ def _train_flops():
         loss, g = jax.value_and_grad(model.loss)(p, b)
         return adamw_update(g, p, o, acfg) + (loss,)
     c = jax.jit(step).lower(params, opt, batch).compile()
-    return cost_analysis_dict(c)["flops"]
+    return c.cost_analysis()["flops"]
 
 
 def test_analytic_train_flops_within_25pct_of_xla():
@@ -44,7 +42,7 @@ def test_analytic_prefill_flops_within_30pct_of_xla():
     batch = {"tokens": jax.ShapeDtypeStruct((4, 128), jnp.int32)}
     c = jax.jit(lambda p, b: model.prefill(p, b, 128)).lower(
         params, batch).compile()
-    xla = cost_analysis_dict(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     an = cell_cost(CFG, ShapeCell("p", 128, 4, "prefill")).flops
     assert 0.7 < an / xla < 1.3, (an, xla)
 
