@@ -31,6 +31,7 @@ import numpy as np
 
 from ..core import constraint, current_runtime, io, task
 from ..core.runtime import copy_fsync
+from ..obs.spans import span, tag
 from .serializer import (flatten_with_paths, plan_shards, read_shard,
                          unflatten_like, write_shard)
 
@@ -129,25 +130,39 @@ class CheckpointManager:
     # ------------------------------------------------------------------ save
     def save(self, step: int, tree, sync: bool = False) -> bool:
         """Async save via the ambient IORuntime; sync=True (or no runtime)
-        writes inline. Returns False if skipped due to an in-flight save."""
+        writes inline. Returns False if skipped due to an in-flight save.
+
+        Traced as ``ckpt.save`` (counts ``step``, ``mode``) with children
+        ``ckpt.overrun_wait``, ``ckpt.snapshot`` (``bytes``), ``ckpt.plan``,
+        ``ckpt.submit`` (``tasks``) and ``ckpt.gc``. The manifest's
+        ``save_seconds`` runs from this call to the commit, the copy to the
+        host included."""
+        t0 = time.monotonic()
+        with span("ckpt.save", step=step):
+            return self._save(step, tree, sync, t0)
+
+    def _save(self, step: int, tree, sync: bool, t0: float) -> bool:
         rt = current_runtime()
         if self._in_flight is not None and rt is not None:
             prev_step, fut = self._in_flight
             if not fut.resolved():
                 if self.overrun_policy == "skip" and not sync:
                     return False
-                rt.wait_on(fut)
+                with span("ckpt.overrun_wait"):
+                    rt.wait_on(fut)
             self._in_flight = None
 
         # device_get returns only once every leaf is on the host, so the
         # snapshot is complete before the caller's next step donates (and
         # overwrites) the device buffers it was read from
-        host_leaves = [(k, np.asarray(jax.device_get(v)))
-                       for k, v in flatten_with_paths(tree)]
-        step_dir = self.dir / f"step_{step:08d}"
-        step_dir.mkdir(parents=True, exist_ok=True)
-        plan = plan_shards(host_leaves, self.n_shards)
-        t0 = time.monotonic()
+        with span("ckpt.snapshot"):
+            host_leaves = [(k, np.asarray(jax.device_get(v)))
+                           for k, v in flatten_with_paths(tree)]
+            tag("bytes", sum(a.nbytes for _, a in host_leaves))
+        with span("ckpt.plan"):
+            step_dir = self.dir / f"step_{step:08d}"
+            step_dir.mkdir(parents=True, exist_ok=True)
+            plan = plan_shards(host_leaves, self.n_shards)
         if rt is None or sync:
             mode = "sync"
             frags = [write_shard(step_dir / f"shard_{i:04d}.bin", entries)
@@ -162,12 +177,15 @@ class CheckpointManager:
             mode = "reroute" if self.fast_dir is not None else "flat"
             fs_hint = "fs" if self.fast_dir is not None \
                 and rt.cluster.has_tier("fs") else None
-            futs = [_write_shard_task(str(step_dir / f"shard_{i:04d}.bin"),
-                                      entries,
-                                      io_mb=sum(a.nbytes for _, a in entries)
-                                      / 1e6, storage_tier=fs_hint)
+            with span("ckpt.submit"):
+                futs = [_write_shard_task(
+                    str(step_dir / f"shard_{i:04d}.bin"), entries,
+                    io_mb=sum(a.nbytes for _, a in entries) / 1e6,
+                    storage_tier=fs_hint)
                     for i, entries in enumerate(plan) if entries]
-            commit = _commit_task(step_dir / "MANIFEST.json", step, futs, t0)
+                commit = _commit_task(step_dir / "MANIFEST.json", step, futs,
+                                      t0)
+                tag("tasks", len(futs) + 1)
             self._in_flight = (step, commit)
         else:
             # burst-buffer mode: absorb the write burst on the fast tier,
@@ -178,38 +196,43 @@ class CheckpointManager:
             fast_step.mkdir(parents=True, exist_ok=True)
             fs_hint = "fs" if rt.cluster.has_tier("fs") else None
             drained = []
-            for i, entries in enumerate(plan):
-                if not entries:
-                    continue
-                name = f"shard_{i:04d}.bin"
-                mb = sum(a.nbytes for _, a in entries) / 1e6
-                wf = _write_shard_task(str(fast_step / name), entries,
-                                       io_mb=mb)
-                drained.append(_drain_shard_task(
-                    wf, str(fast_step / name), str(step_dir / name),
-                    io_mb=mb, storage_tier=fs_hint,
-                    storage_bw=self.drain_bw))
-            commit = _commit_task(step_dir / "MANIFEST.json", step,
-                                  drained, t0)
+            with span("ckpt.submit"):
+                for i, entries in enumerate(plan):
+                    if not entries:
+                        continue
+                    name = f"shard_{i:04d}.bin"
+                    mb = sum(a.nbytes for _, a in entries) / 1e6
+                    wf = _write_shard_task(str(fast_step / name), entries,
+                                           io_mb=mb)
+                    drained.append(_drain_shard_task(
+                        wf, str(fast_step / name), str(step_dir / name),
+                        io_mb=mb, storage_tier=fs_hint,
+                        storage_bw=self.drain_bw))
+                commit = _commit_task(step_dir / "MANIFEST.json", step,
+                                      drained, t0)
+                tag("tasks", 2 * len(drained) + 1)
             self._in_flight = (step, commit)
+        tag("mode", mode)
         rec = getattr(rt, "recorder", None)
         if rec is not None:
             rec.on_ckpt("save", step, mode,
                         sum(1 for entries in plan if entries))
-        self._gc()
+        with span("ckpt.gc"):
+            self._gc()
         return True
 
     def wait(self):
-        rt = current_runtime()
-        if self._in_flight is not None and rt is not None:
-            step = self._in_flight[0]
-            rt.wait_on(self._in_flight[1])
-            self._in_flight = None
-            rec = getattr(rt, "recorder", None)
-            if rec is not None:
-                rec.on_ckpt("wait", step, "async", 0)
-            # the last save just became durable: one final fast-tier trim
-            self._gc()
+        with span("ckpt.wait"):
+            rt = current_runtime()
+            if self._in_flight is not None and rt is not None:
+                step = self._in_flight[0]
+                rt.wait_on(self._in_flight[1])
+                self._in_flight = None
+                rec = getattr(rt, "recorder", None)
+                if rec is not None:
+                    rec.on_ckpt("wait", step, "async", 0)
+                # the last save just became durable: one final fast-tier trim
+                self._gc()
 
     # --------------------------------------------------------------- restore
     def steps(self) -> list[int]:

@@ -22,6 +22,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, Optional
 
+from ..obs import spans as _spans
 from .scheduler import SchedulerError
 from .storage_model import per_task_rate
 from .task import Future, TaskInstance, TaskState, TaskType
@@ -394,7 +395,7 @@ class SimBackend(Backend):
     def _fail_attempt(self, task: TaskInstance, error: BaseException) -> bool:
         """One attempt of ``task`` failed (injected fault or its device went
         offline). While attempts remain (``maxRetries``, same arithmetic as
-        RealBackend._run: ``max_retries + 1`` attempts, ``task.retries``
+        RealBackend._execute: ``max_retries + 1`` attempts, ``task.retries``
         counting failed ones) the task re-enters the ready queue for a
         fresh grant — on a surviving eligible device — and True is
         returned; otherwise the task is FAILED and False is returned (the
@@ -687,9 +688,24 @@ class RealBackend(Backend):
                 task.start_time, task.device)
         if self.recorder is not None:
             self.recorder.on_launch(task, worker)
+        stamp = getattr(task, "_span", None)
+        if stamp is not None:
+            # submit to launch: admission by the tuner and the executor limit
+            submitted_ns, parent = stamp
+            _spans.record(f"io.queued:{task.defn.signature}", submitted_ns,
+                          time.perf_counter_ns(), parent, tid=task.tid,
+                          inflight=task._telemetry_k)
         self._pool(worker, platform).submit(self._run, task)
 
     def _run(self, task: TaskInstance) -> None:
+        if not _spans.enabled():
+            return self._execute(task)
+        stamp = getattr(task, "_span", None)
+        with _spans.span(f"io.run:{task.defn.signature}",
+                         parent=stamp[1] if stamp else None, tid=task.tid):
+            self._execute(task)
+
+    def _execute(self, task: TaskInstance) -> None:
         args = tuple(self._resolve(a) for a in task.args)
         kwargs = {k: self._resolve(v) for k, v in task.kwargs.items()}
         err: Optional[BaseException] = None
@@ -720,7 +736,7 @@ class RealBackend(Backend):
                 f.set_value(v)
         else:
             task.futures[0].set_value(result)
-        with self._cv:
+        with _spans.locked(self._cv):
             if task.defn.task_type == TaskType.IO and task.device is not None:
                 # measured sample under the runtime lock (same critical
                 # section as the complete event, so trace order matches)
@@ -744,7 +760,7 @@ class RealBackend(Backend):
 
     def drain(self, predicate: Callable[[], bool]) -> None:
         rt = self.runtime
-        with self._cv:
+        with _spans.locked(self._cv):
             while True:
                 rt.scheduler.schedule_pass()
                 if self._failed:

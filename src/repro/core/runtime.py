@@ -46,12 +46,14 @@ from __future__ import annotations
 import os
 import shutil
 import threading
+import time
 from contextlib import contextmanager
 from typing import Optional
 
 from .autotune import DriftConfig
 from .backends import Backend, RealBackend, SimBackend
 from ..obs import TraceConfig, TraceRecorder
+from ..obs import spans as _spans
 from .constraints import parse_storage_bw
 from .datalife import DataCatalog, LifecycleConfig
 from .failures import FailureEngine
@@ -460,7 +462,8 @@ class IORuntime:
     # ------------------------------------------------------------- submission
     def submit(self, defn: TaskDef, args, kwargs, sim: SimSpec,
                storage_bw=None, storage_tier=None, shard_key=None):
-        with self.lock:
+        traced = _spans.enabled()
+        with _spans.locked(self.lock, traced):
             if self.capture_mode:
                 # record-only path: no staging, no constraint validation
                 # (unsatisfiable classes become IO1xx diagnostics instead of
@@ -505,6 +508,10 @@ class IORuntime:
             if self.recorder is not None:
                 self.recorder.on_submit(inst)
             ready = self.graph.add(inst)
+            if traced:
+                # submit stamp (left unset untraced): time and submitting
+                # span, for RealBackend's io.queued record and io.run parent
+                inst._span = (time.perf_counter_ns(), _spans.current())
             if inst.state != TaskState.FAILED:
                 # scheduled-reader tracking (LRU clock + eviction guard);
                 # tasks cancelled at add never run, so they never register

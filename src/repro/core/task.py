@@ -130,14 +130,16 @@ class TaskInstance:
     # log keeps every instance alive to the end of the run, and the cache
     # pressure of those dicts is what bends the per-task cost superlinear.
     # _plan_seq is capture-mode-only and deliberately left unset elsewhere
-    # (the lint rules read it via getattr-with-default).
+    # (the lint rules read it via getattr-with-default); so is _span, the
+    # submit stamp IORuntime.submit sets only while spans are traced.
     __slots__ = (
         "tid", "defn", "args", "kwargs", "sim", "storage_bw", "tier",
         "state", "deps", "anti_deps", "children", "futures", "worker",
         "device", "granted_bw", "tuner_key", "reserved_mb", "read_penalty",
         "_datalife", "submit_time", "start_time", "end_time",
         "measured_duration", "_telemetry_k", "epoch", "retries", "error",
-        "_ready_seq", "_sim_seq", "shard", "shard_key", "_plan_seq")
+        "_ready_seq", "_sim_seq", "shard", "shard_key", "_plan_seq",
+        "_span")
 
     def __init__(self, defn: TaskDef, args: tuple, kwargs: dict,
                  sim: SimSpec | None = None,

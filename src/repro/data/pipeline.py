@@ -7,6 +7,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..core import current_runtime, io, task
+from ..obs.spans import span, tag
 
 
 class SyntheticCorpus:
@@ -56,11 +57,14 @@ class PrefetchLoader:
         self._pending: dict[int, object] = {}
 
     def get(self, step: int) -> dict:
-        rt = current_runtime()
-        if rt is None:
-            return self.corpus.batch(step)
-        for s in range(step, step + self.depth + 1):
-            if s not in self._pending:
-                self._pending[s] = _fetch_task(self.corpus, s)
-        fut = self._pending.pop(step)
-        return rt.wait_on(fut)
+        with span("loader.get"):
+            rt = current_runtime()
+            if rt is None:
+                return self.corpus.batch(step)
+            for s in range(step, step + self.depth + 1):
+                if s not in self._pending:
+                    self._pending[s] = _fetch_task(self.corpus, s)
+            fut = self._pending.pop(step)
+            tag("ready", int(fut.resolved()))
+            with span("loader.wait"):
+                return rt.wait_on(fut)

@@ -7,6 +7,10 @@ Enable per-runtime with ``IORuntime(cluster, trace=True)`` (or pass a
 repro.trace`` CLI instead sets :data:`FORCE`, which turns tracing on for
 every runtime a script constructs and registers it here — the same
 hijack pattern ``repro.lint`` uses for capture mode.
+
+Program spans (:mod:`repro.obs.spans`) are separate from the recorder:
+process-wide, on the profiler's clock, and on exactly while a JAX profiler
+trace is being collected.
 """
 from __future__ import annotations
 
@@ -14,7 +18,7 @@ from .recorder import (EVENT_SCHEMA, WAIT_STATES, MetricsTimeline,
                        TraceConfig, TraceRecorder)
 from .telemetry import (TelemetryHub, apply_tier_config, fit_samples,
                         fit_tiers)
-from . import compare, perfetto, report
+from . import compare, perfetto, report, spans
 
 #: When true, every IORuntime constructed enables tracing and registers
 #: its recorder in RUNS (set only by the ``repro.trace`` CLI driver).
@@ -40,6 +44,6 @@ def register(runtime) -> None:
 __all__ = [
     "EVENT_SCHEMA", "WAIT_STATES", "MetricsTimeline", "TraceConfig",
     "TraceRecorder", "TelemetryHub", "apply_tier_config", "fit_samples",
-    "fit_tiers", "compare", "perfetto", "report", "FORCE", "RUNS",
+    "fit_tiers", "compare", "perfetto", "report", "spans", "FORCE", "RUNS",
     "FORCE_BACKEND", "register",
 ]

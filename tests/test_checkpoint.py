@@ -2,6 +2,7 @@
 elastic restore."""
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -87,3 +88,31 @@ def test_restore_with_new_shardings(tmp_path):
     assert_tree_equal(t, restored)
     leaf = jax.tree.leaves(restored)[0]
     assert isinstance(leaf, jax.Array)
+
+
+@pytest.mark.parametrize("mode", ["sync", "flat", "burst-buffer"])
+def test_save_seconds_counts_the_copy(tmp_path, monkeypatch, mode):
+    """The manifest's save_seconds runs from the save() call, so it holds
+    the device-to-host copy, in every mode."""
+    real_get = jax.device_get
+
+    def slow_get(x):
+        time.sleep(0.05)
+        return real_get(x)
+    monkeypatch.setattr(jax, "device_get", slow_get)
+    t = tree()
+    copy_s = 0.05 * len(jax.tree.leaves(t))
+    fast = tmp_path / "bb" if mode == "burst-buffer" else None
+    mgr = CheckpointManager(tmp_path / "fs", n_shards=2, fast_dir=fast)
+    if mode == "sync":
+        mgr.save(3, t, sync=True)
+    else:
+        dev = StorageDevice(name="fs", bandwidth=2000, per_stream_cap=500)
+        cluster = Cluster(workers=[WorkerNode(
+            name="w0", cpus=2, io_executors=4, storage=dev)])
+        with IORuntime(cluster, backend=RealBackend()):
+            assert mgr.save(3, t)
+            mgr.wait()
+    manifest = json.loads(
+        (tmp_path / "fs" / "step_00000003" / "MANIFEST.json").read_text())
+    assert manifest["save_seconds"] >= copy_s
