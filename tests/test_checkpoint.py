@@ -3,14 +3,19 @@ elastic restore."""
 import json
 import os
 import time
+import tracemalloc
 
 import jax
 import jax.numpy as jnp
+import ml_dtypes
 import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointManager
+from repro.checkpoint import serializer
+from repro.checkpoint.serializer import read_shard, write_shard
 from repro.core import Cluster, IORuntime, RealBackend, StorageDevice, WorkerNode
+from repro.obs import spans
 
 
 def tree():
@@ -116,3 +121,89 @@ def test_save_seconds_counts_the_copy(tmp_path, monkeypatch, mode):
     manifest = json.loads(
         (tmp_path / "fs" / "step_00000003" / "MANIFEST.json").read_text())
     assert manifest["save_seconds"] >= copy_s
+
+
+def traced_write(path, entries):
+    """write_shard under the profiler: (fragment, the ckpt.shard counts)."""
+    spans.clear()
+    with jax.profiler.trace(str(path.parent / "trace")):
+        frag = write_shard(path, entries)
+    (shard,) = [r for r in spans.records() if r.name == "ckpt.shard"]
+    spans.clear()
+    return frag, shard.counts
+
+
+LEAVES = {
+    "f32_2d": lambda: np.arange(12, dtype=np.float32).reshape(3, 4),
+    "bf16": lambda: np.linspace(-2, 2, 10).astype(ml_dtypes.bfloat16),
+    "scalar": lambda: np.asarray(np.int32(7)),
+    "transposed": lambda: np.arange(24, dtype=np.float32).reshape(4, 6).T,
+    "transposed_3d": lambda: np.arange(30, dtype=np.float32)
+    .reshape(5, 3, 2).transpose(2, 1, 0),
+    "strided_bf16": lambda: np.linspace(-1, 1, 40)
+    .astype(ml_dtypes.bfloat16)[::3],
+    "int32": lambda: np.arange(-5, 5, dtype=np.int32).reshape(2, 5),
+}
+
+
+@pytest.mark.parametrize("stage_bytes", [serializer.STAGE_BYTES, 8],
+                         ids=["stage_default", "stage_8B"])
+@pytest.mark.parametrize("case", sorted(LEAVES))
+def test_write_shard_matches_tobytes_format(tmp_path, monkeypatch, case,
+                                            stage_bytes):
+    """Shard files and manifest fragments are what the tobytes format
+    wrote, byte for byte, and read back to the same arrays; only a leaf
+    that is not C-contiguous is copied, in blocks of the staging buffer
+    (8 bytes: split down to single elements)."""
+    monkeypatch.setattr(serializer, "STAGE_BYTES", stage_bytes)
+    leaf = LEAVES[case]()
+    entries = [("x", leaf), ("y", np.arange(5, dtype=np.float32))]
+    frag, counts = traced_write(tmp_path / "shard.bin", entries)
+
+    blobs = [np.asarray(a).tobytes() for _, a in entries]
+    assert (tmp_path / "shard.bin").read_bytes() == b"".join(blobs)
+    offsets = np.cumsum([0] + [len(b) for b in blobs])
+    assert frag == {
+        "file": "shard.bin", "total_bytes": int(offsets[-1]),
+        "entries": {k: {"shape": list(a.shape), "dtype": str(a.dtype),
+                        "offset": int(o), "nbytes": len(b)}
+                    for (k, a), o, b in zip(entries, offsets, blobs)}}
+
+    out = {}
+    read_shard(tmp_path / "shard.bin", frag, out)
+    for k, a in entries:
+        assert out[k].dtype == a.dtype and out[k].shape == a.shape
+        np.testing.assert_array_equal(out[k], a)
+    assert counts["bytes"] == offsets[-1]
+    assert counts["copied_bytes"] == \
+        (0 if leaf.flags.c_contiguous else leaf.nbytes)
+    assert (counts["copied_bytes"] > 0) == (case in (
+        "transposed", "transposed_3d", "strided_bf16"))
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "transposed"])
+def test_write_shard_host_copy_is_bounded(tmp_path, layout):
+    """A contiguous 64 MB leaf is written from its own buffer, with no new
+    allocation near its size (the tobytes format made a 64 MB bytes); a
+    transposed one is copied through the staging buffer alone."""
+    leaf = np.ones((4096, 4096), np.float32)
+    if layout == "transposed":
+        leaf = leaf.T
+    spans.clear()
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        tracemalloc.start()
+        try:
+            frag = write_shard(tmp_path / "shard.bin", [("w", leaf)])
+            _, peak = tracemalloc.get_traced_memory()
+        finally:
+            tracemalloc.stop()
+    (shard,) = [r for r in spans.records() if r.name == "ckpt.shard"]
+    spans.clear()
+    if layout == "contiguous":
+        assert peak < 1 << 20
+        assert shard.counts["copied_bytes"] == 0
+    else:
+        assert peak < serializer.STAGE_BYTES + (1 << 20)
+        assert shard.counts["copied_bytes"] == leaf.nbytes
+    assert frag["total_bytes"] == leaf.nbytes == 64 << 20
+    assert (tmp_path / "shard.bin").stat().st_size == leaf.nbytes

@@ -123,6 +123,7 @@ def test_save_tree(traced_save):
     assert sum(s.counts["bytes"] for s in shards) == children[0].counts["bytes"]
     for s in shards:
         assert {"serialize_ns", "write_ns", "fsync_ns"} <= set(s.counts)
+        assert s.counts["copied_bytes"] == 0    # the snapshot is C order
         (fsync,) = [r for r in recs if r.parent == s.id]
         assert fsync.name == "ckpt.shard.fsync"
         assert fsync.seconds * 1e9 <= s.counts["fsync_ns"]
