@@ -15,7 +15,9 @@ the program:
   mean taken over them.
 
 A step that returns its state unchanged reads 1 on ``change_gap`` by the
-measure itself and needs no run. The benchmark's own runs never run this.
+measure itself and needs no run. Where the configuration states a layout,
+every reference here is spread over the cell's chips, as in its runs. The
+benchmark's own runs never run this.
 Prints one JSON line of readings; ``--out`` also writes it to a file.
 """
 from __future__ import annotations
@@ -72,6 +74,7 @@ def main(argv=None) -> int:
     opt = conf["train"]["optimizer"]
     B, S = conf["train"]["batch"], conf["train"]["seq"]
     rb = conf["reference"]["row_block"]
+    devices = jax.devices()[:cell.chips] if "layout" in conf else None
     corpus_kw = {k: mix["corpus"][k] for k in ("structured", "noise")}
     control, half = [], []
     for seed in seeds[:args.control_seeds]:
@@ -81,13 +84,14 @@ def main(argv=None) -> int:
         batches = [(jnp.asarray(b["tokens"]), jnp.asarray(b["targets"]))
                    for b in rows]
         f32 = dict(compute_dtype=jnp.float32, param_dtype=jnp.float32)
-        full = follow(ref, m, opt, key, batches, row_block=rb, **f32)
+        full = follow(ref, m, opt, key, batches, row_block=rb,
+                      devices=devices, **f32)
         low = follow(ref, m, opt, key, batches, row_block=rb,
                      compute_dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
-                     precision="default")
+                     precision="default", devices=devices)
         part = follow(ref, m, opt, key,
                       [(t[:B // 2], y[:B // 2]) for t, y in batches],
-                      row_block=rb, **f32)
+                      row_block=rb, devices=devices, **f32)
         control.append({"seed": seed, **check.training_gaps(low, full)})
         half.append({"seed": seed, **check.training_gaps(part, full)})
         print(json.dumps({"control": control[-1], "half_batch": half[-1]}),

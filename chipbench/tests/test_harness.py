@@ -75,3 +75,25 @@ def test_altered_token_is_not_correct(monkeypatch):
     out = _run()
     assert not out["correct"], out["checks"]
     assert out["checks"]["batch_mismatch"][0] > 0
+
+
+def test_one_chip_builds_no_mesh():
+    """A configuration without a layout traces the program's step with no
+    mesh entered, on arguments that each live on one device."""
+    from repro.distributed import current_mesh
+    from repro.launch.train import make_train_step
+    seen = []
+
+    def recording(model, opt):
+        inner = make_train_step(model, opt)
+
+        def step(params, opt_state, batch):
+            seen.append((current_mesh(), {
+                len(a.sharding.device_set)
+                for a in jax.tree.leaves((params, opt_state, batch))}))
+            return inner(params, opt_state, batch)
+        return step
+    out = _run(recording)
+    assert out["correct"], out["checks"]
+    assert seen and all(mesh is None and n == {1} for mesh, n in seen)
+    assert out["device"]["count"] == 1

@@ -58,3 +58,19 @@ def test_an_operation_counts_its_self_time():
     assert got == pytest.approx({"while.1 s32[]": 70, "fusion.2 f32[4,8]":
                                  20, "fusion.3 bf16[2]": 10,
                                  "copy.4 f32[]": 20})
+
+
+def test_several_chips_read_per_chip(monkeypatch):
+    """Two chips, one busy 60 ns and one 20 ns of a 100 ns window: busy
+    time, each operation's time and the idle time are their means."""
+    op = "%fusion.1 = f32[8]{0} fusion(...)"
+    devices = {"/device:TPU:0": [(op, 10, 70)],
+               "/device:TPU:1": [(op, 10, 30)]}
+    host = [("window", 0, 100), ("block", 0, 100)]
+    monkeypatch.setattr(trace_reduce, "read_events",
+                        lambda path: (devices, host))
+    out = trace_reduce.reduce("two.xplane.pb", {"block"})
+    assert out["n_devices"] == 2
+    assert out["busy_s"] == pytest.approx(40e-9)
+    assert dict(out["device_ops"]) == pytest.approx({"fusion.1 f32[8]": 40e-9})
+    assert dict(out["idle_gaps"]) == pytest.approx({"block": 60e-9})

@@ -95,8 +95,10 @@ def reduce(path, span_names, top: int = 10) -> dict:
     time and the idle time by the innermost of ``span_names`` that covers
     it.
 
-    Busy time is averaged over the device planes that ran an operation in
-    the window. Idle time with no such span over it is named ``other``."""
+    Busy time, each operation's time and the idle time are averaged over
+    the device planes that ran an operation in the window, so that each
+    reads per chip. Idle time with no such span over it is named
+    ``other``."""
     devices, host = read_events(path)
     windows = [(s, e) for n, s, e in host if n == WINDOW]
     if not windows:
@@ -123,6 +125,7 @@ def reduce(path, span_names, top: int = 10) -> dict:
                     idle[name] += sec / 1e9
     if not busy:
         raise ValueError(f"{path}: no device operation in the window")
+    op_time = {k: v / len(busy) for k, v in op_time.items()}
     idle = {k: v / len(busy) for k, v in idle.items()}
     rank = lambda d: sorted(([k, v] for k, v in d.items()),
                             key=lambda kv: -kv[1])[:top]

@@ -14,9 +14,16 @@ steps through the window's own step, loader and state. Those steps are what
 the plain reference follows once the window has closed and the program's
 state is freed. The window then goes on with the same state from the next
 step, and ends at the first step boundary at or after ``seconds``.
+
+Where the configuration states a ``layout`` (``spec.py``), all of the
+program's side runs inside the program's mesh context over the cell's
+chips: the weights are made straight into the program's shardings, AdamW's
+state beside them, and each batch is split over the strategy's batch axes.
+Without one, nothing of that is built and JAX places every array itself.
 """
 from __future__ import annotations
 
+import contextlib
 import gc
 import json
 import math
@@ -65,105 +72,125 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
     ckpt = mix.get("checkpoint")
     key = seed_key(seed)
     spans, clock = Spans(), CompileClock()
-    device = jax.devices()[0]
+    devices = jax.devices()[:cell.chips]
+    if "layout" in conf:
+        place = model_under_test.Sharded(
+            cfg, conf["layout"], model_under_test.layout_mesh(conf["layout"]),
+            B)
+        program = place.context()
+        pin = lambda name: {"out_shardings": getattr(place, name)}
+        to_device = lambda host_batch: jax.device_put(host_batch, place.batch)
+    else:
+        program = contextlib.nullcontext()
+        pin = lambda name: {}
+        to_device = lambda host_batch: {k: jnp.asarray(v)
+                                        for k, v in host_batch.items()}
 
-    init = jax.jit(lambda k: ref.init_params(k, m, dtype))
-    params = init(key)
-    model_under_test.check_layout(cfg, params)
-    state = {"params": params, "opt": jax.jit(adamw_init)(params)}
-    del params
-    step = (make_step or make_train_step)(Model(cfg), AdamWConfig(**opt))
-    first_grad = jax.jit(lambda mom: leaf_norms(
-        jax.tree.map(lambda a: a / (1 - opt["b1"]), mom)))
-    change = jax.jit(lambda p, k: leaf_norms(jax.tree.map(
-        lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
-        p, init(k))))
-    checksum = jax.jit(device_checksums)
-    corpus = SyntheticCorpus(cfg.vocab_size, S, B, seed=seed, **corpus_kw)
+    with program:
+        init = jax.jit(lambda k: ref.init_params(k, m, dtype), **pin("params"))
+        params = init(key)
+        model_under_test.check_layout(cfg, params)
+        state = {"params": params,
+                 "opt": jax.jit(adamw_init, **pin("opt"))(params)}
+        del params
+        step = (make_step or make_train_step)(Model(cfg), AdamWConfig(**opt))
+        first_grad = jax.jit(lambda mom: leaf_norms(
+            jax.tree.map(lambda a: a / (1 - opt["b1"]), mom)))
+        change = jax.jit(lambda p, k: leaf_norms(jax.tree.map(
+            lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
+            p, init(k))))
+        checksum = jax.jit(device_checksums)
+        corpus = SyntheticCorpus(cfg.vocab_size, S, B, seed=seed, **corpus_kw)
 
-    prog = {"losses": [], "gnorms": []}
-    numbers = {"batch_mismatch": 0}
-    n_steps = n_nonfinite = 0
-    save = None
-    work = Path(tempfile.mkdtemp(prefix="chipbench_"))
-    try:
-        with IORuntime(build_cluster(), backend=RealBackend()) as rt:
-            loader = PrefetchLoader(corpus, depth=mix["prefetch_depth"])
+        prog = {"losses": [], "gnorms": []}
+        numbers = {"batch_mismatch": 0}
+        n_steps = n_nonfinite = 0
+        save = None
+        work = Path(tempfile.mkdtemp(prefix="chipbench_"))
+        try:
+            with IORuntime(build_cluster(), backend=RealBackend()) as rt:
+                loader = PrefetchLoader(corpus, depth=mix["prefetch_depth"])
 
-            def one_step(i):
-                with spans("loader_get"):
-                    host_batch = loader.get(i)
-                with spans("to_device"):
-                    batch = {k: jnp.asarray(v) for k, v in host_batch.items()}
-                with spans("train_step"):
-                    p, o, loss, gnorm = step(state["params"], state["opt"],
-                                             batch)
-                with spans("block"):
-                    jax.block_until_ready((p, o, loss))
-                state["params"], state["opt"] = p, o
-                with spans("loss_read"):
-                    return host_batch, float(loss), gnorm
+                def one_step(i):
+                    with spans("loader_get"):
+                        host_batch = loader.get(i)
+                    with spans("to_device"):
+                        batch = to_device(host_batch)
+                    with spans("train_step"):
+                        p, o, loss, gnorm = step(state["params"], state["opt"],
+                                                 batch)
+                    with spans("block"):
+                        jax.block_until_ready((p, o, loss))
+                    state["params"], state["opt"] = p, o
+                    with spans("loss_read"):
+                        return host_batch, float(loss), gnorm
 
-            for i in range(n_follow):
-                host_batch, loss, gnorm = one_step(i)
-                want = traffic.batch(seed, i, B, S, cfg.vocab_size, **corpus_kw)
-                numbers["batch_mismatch"] += sum(
-                    not np.array_equal(host_batch[k], want[k]) for k in want)
-                prog["losses"].append(loss)
-                prog["gnorms"].append(float(gnorm))
-                if i == 0:
-                    prog["grad_norms"] = to_floats(first_grad(state["opt"].m))
-            prog["change_norms"] = to_floats(change(state["params"], key))
+                for i in range(n_follow):
+                    host_batch, loss, gnorm = one_step(i)
+                    want = traffic.batch(seed, i, B, S, cfg.vocab_size,
+                                         **corpus_kw)
+                    numbers["batch_mismatch"] += sum(
+                        not np.array_equal(host_batch[k], want[k])
+                        for k in want)
+                    prog["losses"].append(loss)
+                    prog["gnorms"].append(float(gnorm))
+                    if i == 0:
+                        prog["grad_norms"] = to_floats(
+                            first_grad(state["opt"].m))
+                prog["change_norms"] = to_floats(change(state["params"], key))
+                if ckpt:
+                    mgr = CheckpointManager(work / "ckpt",
+                                            n_shards=ckpt["n_shards"])
+                    jax.block_until_ready(
+                        checksum((state["params"], state["opt"])))
+                setup_s = time.monotonic() - t_start
+
+                if trace:
+                    jax.profiler.start_trace(str(work / "trace"))
+                spans.seconds.clear()
+                compiles_before = clock.compiles
+                i = n_follow
+                with spans("window"):
+                    t0 = time.perf_counter()
+                    while True:
+                        _, loss, _ = one_step(i)
+                        i += 1
+                        n_steps += 1
+                        n_nonfinite += not math.isfinite(loss)
+                        if ckpt and save is None \
+                                and time.perf_counter() - t0 >= ckpt["at_s"]:
+                            tree = (state["params"], state["opt"])
+                            save = {"step": i - 1, "sums": checksum(tree),
+                                    "call_ns": time.time_ns(),
+                                    "call_rt": rt.backend.now()}
+                            with spans("ckpt_save"):
+                                save["started"] = mgr.save(i - 1, tree)
+                            del tree
+                        if time.perf_counter() - t0 >= seconds:
+                            break
+                    window_s = time.perf_counter() - t0
+                if trace:
+                    jax.profiler.stop_trace()
+                compiles = clock.compiles - compiles_before
+                if save:
+                    mgr.wait()
+                    save["samples"] = [
+                        s for d in rt.backend.telemetry.devices.values()
+                        for s in d.samples
+                        if s[0] >= save["call_rt"] and s[1] > 0]
+            memory_peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+                            for d in devices]
+            like = jax.eval_shape(lambda: (state["params"], state["opt"]))
+            state.clear()
+            del loader
+            gc.collect()
+
             if ckpt:
-                mgr = CheckpointManager(work / "ckpt", n_shards=ckpt["n_shards"])
-                jax.block_until_ready(checksum((state["params"], state["opt"])))
-            setup_s = time.monotonic() - t_start
-
-            if trace:
-                jax.profiler.start_trace(str(work / "trace"))
-            spans.seconds.clear()
-            compiles_before = clock.compiles
-            i = n_follow
-            with spans("window"):
-                t0 = time.perf_counter()
-                while True:
-                    _, loss, _ = one_step(i)
-                    i += 1
-                    n_steps += 1
-                    n_nonfinite += not math.isfinite(loss)
-                    if ckpt and save is None \
-                            and time.perf_counter() - t0 >= ckpt["at_s"]:
-                        tree = (state["params"], state["opt"])
-                        save = {"step": i - 1, "sums": checksum(tree),
-                                "call_ns": time.time_ns(),
-                                "call_rt": rt.backend.now()}
-                        with spans("ckpt_save"):
-                            save["started"] = mgr.save(i - 1, tree)
-                        del tree
-                    if time.perf_counter() - t0 >= seconds:
-                        break
-                window_s = time.perf_counter() - t0
-            if trace:
-                jax.profiler.stop_trace()
-            compiles = clock.compiles - compiles_before
-            if save:
-                mgr.wait()
-                save["samples"] = [
-                    s for d in rt.backend.telemetry.devices.values()
-                    for s in d.samples if s[0] >= save["call_rt"] and s[1] > 0]
-        stats = device.memory_stats() or {}
-        memory_peak = stats.get("peak_bytes_in_use")
-        like = jax.eval_shape(lambda: (state["params"], state["opt"]))
-        state.clear()
-        del loader
-        gc.collect()
-
-        if ckpt:
-            numbers.update(_check_save(save, mgr, work / "ckpt", like))
-        traced = reduce_trace(next((work / "trace").rglob("*.xplane.pb")),
-                              STEP_SPANS) if trace else None
-    finally:
-        shutil.rmtree(work, ignore_errors=True)
+                numbers.update(_check_save(save, mgr, work / "ckpt", like))
+            traced = reduce_trace(next((work / "trace").rglob("*.xplane.pb")),
+                                  STEP_SPANS) if trace else None
+        finally:
+            shutil.rmtree(work, ignore_errors=True)
 
     batches = [traffic.batch(seed, i, B, S, cfg.vocab_size, **corpus_kw)
                for i in range(n_follow)]
@@ -171,7 +198,8 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
                       [(jnp.asarray(b["tokens"]), jnp.asarray(b["targets"]))
                        for b in batches],
                       compute_dtype=jnp.float32, param_dtype=jnp.float32,
-                      row_block=conf["reference"]["row_block"])
+                      row_block=conf["reference"]["row_block"],
+                      devices=devices if "layout" in conf else None)
     numbers.update(check.training_gaps(prog, followed))
     correct, rows = check.verdict(numbers, cell.limits)
 
@@ -185,7 +213,7 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
     ctx = {"window_s": window_s, "steps": n_steps, "tokens": tokens,
            "spans": dict(spans.seconds), "save": save, "trace": traced,
            "flops_per_step": cell.flops.train_step_flops(m, B, S),
-           "peak": peaks}
+           "peak": peaks, "chips": cell.chips}
     if trace:
         wanted = cell.per_layer
         values = {mt["name"]: metric_reader(mt["name"])(ctx) for mt in wanted}
@@ -195,8 +223,11 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
     metrics = {mt["name"]: {"value": values[mt["name"]], "unit": mt["unit"]}
                for mt in wanted if values[mt["name"]] is not None}
 
-    dev = {"platform": device.platform, "kind": device.device_kind,
-           "count": len(jax.devices()), "memory_peak_bytes": memory_peak}
+    dev = {"platform": devices[0].platform, "kind": devices[0].device_kind,
+           "count": len(devices),
+           "memory_peak_bytes": None if None in memory_peaks
+           else max(memory_peaks),
+           "memory_peak_bytes_per_chip": memory_peaks}
     result = {"correct": correct,
               "attempted": n_steps + (1 if ckpt else 0),
               "failed": n_nonfinite + failed_saves,
