@@ -20,7 +20,9 @@ def ssd_scan_ref(x, dt, B, C, la, D):
         la_c, x_c, b_c, c_c, dt_c = inp
         lcum = jnp.cumsum(la_c, axis=1)
         seg = lcum[:, :, None, :] - lcum[:, None, :, :]
-        L = jnp.where(causal[None, :, :, None], jnp.exp(seg), 0.0)
+        # masked before the exponential, as in models/mamba2.ssd_chunked:
+        # an overflowed exp(seg) above the diagonal makes the VJP NaN
+        L = jnp.exp(jnp.where(causal[None, :, :, None], seg, -jnp.inf))
         cb = jnp.einsum("bin,bjn->bij", c_c, b_c)
         w = cb[..., None] * L
         xdt = x_c.astype(jnp.float32) * dt_c[..., None]

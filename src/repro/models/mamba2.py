@@ -98,7 +98,10 @@ def ssd_chunked(x, dt, B, C, A_log, D, chunk: int, use_kernel: bool = False):
         la_c, x_c, b_c, c_c, dt_c = inp            # (b,Q,H) (b,Q,H,P) (b,Q,N)...
         lcum = jnp.cumsum(la_c, axis=1)            # (b,Q,H)
         seg = lcum[:, :, None, :] - lcum[:, None, :, :]      # (b,Q,Q,H)
-        L = jnp.where(causal[None, :, :, None], jnp.exp(seg), 0.0)
+        # masked before the exponential: above the diagonal seg is large
+        # and positive, exp(seg) overflows, and the gradient of a where
+        # over an inf is NaN
+        L = jnp.exp(jnp.where(causal[None, :, :, None], seg, -jnp.inf))
         cb = jnp.einsum("bin,bjn->bij", c_c, b_c)            # (b,Q,Q)
         w = cb[..., None] * L                                # (b,Q,Q,H)
         xdt = x_c.astype(jnp.float32) * dt_c[..., None]      # (b,Q,H,P)
@@ -114,7 +117,8 @@ def ssd_chunked(x, dt, B, C, A_log, D, chunk: int, use_kernel: bool = False):
     xs = (jnp.moveaxis(la, 1, 0), jnp.moveaxis(xr, 1, 0),
           jnp.moveaxis(Br, 1, 0), jnp.moveaxis(Cr, 1, 0),
           jnp.moveaxis(dtr, 1, 0))
-    h_last, ys = jax.lax.scan(scan_fn, h0, xs)
+    with jax.named_scope("ssd_scan"):
+        h_last, ys = jax.lax.scan(scan_fn, h0, xs)
     y = jnp.moveaxis(ys, 0, 1).astype(x.dtype).reshape(b, S, H, Pd)
     y = y + (D[:, None] * x.astype(jnp.float32)).astype(x.dtype)
     return y, h_last
@@ -137,26 +141,39 @@ class MambaCache(NamedTuple):
         )
 
 
+def _last_inputs(x, K):
+    """The last K-1 positions of x (B, S, C), zero rows before the first."""
+    return jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))[:, x.shape[1]:]
+
+
+def _gated_norm(y, z, w):
+    """Mamba-2's RMSNormGated (norm_before_gate=False, one group): the gate
+    is applied first and the product normalised."""
+    return rmsnorm(y * jax.nn.silu(z), w)
+
+
 def mamba2_forward(p, u, *, chunk=256, use_kernel=False):
-    """u: (B, S, D) -> (B, S, D); returns (out, final_state)."""
+    """u: (B, S, D) -> (B, S, D); returns (out, the cache a decode continues
+    from: the last conv inputs and the final SSM state)."""
     Bsz, S, Dm = u.shape
     d_inner = p["out_proj"].shape[0]
     H = p["A_log"].shape[0]
     Pd = d_inner // H
     N = p["in_bc"].shape[1] // 2
+    K = p["conv_x"].shape[0]
     z = jnp.einsum("bsd,de->bse", u, p["in_z"])
-    x = jnp.einsum("bsd,de->bse", u, p["in_x"])
-    bc = jnp.einsum("bsd,de->bse", u, p["in_bc"])
+    x_in = jnp.einsum("bsd,de->bse", u, p["in_x"])
+    bc_in = jnp.einsum("bsd,de->bse", u, p["in_bc"])
     dt = jnp.einsum("bsd,dh->bsh", u, p["in_dt"])
-    x = _causal_conv(x, p["conv_x"], p["conv_x_b"]).reshape(Bsz, S, H, Pd)
-    bc = _causal_conv(bc, p["conv_bc"], p["conv_bc_b"])
+    x = _causal_conv(x_in, p["conv_x"], p["conv_x_b"]).reshape(Bsz, S, H, Pd)
+    bc = _causal_conv(bc_in, p["conv_bc"], p["conv_bc_b"])
     Bm, Cm = bc[..., :N], bc[..., N:]
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     y, h_last = ssd_chunked(x, dt, Bm, Cm, p["A_log"], p["D"], chunk,
                             use_kernel=use_kernel)
-    y = y.reshape(Bsz, S, d_inner)
-    y = rmsnorm(y, p["norm_w"]) * jax.nn.silu(z)
-    return jnp.einsum("bse,ed->bsd", y, p["out_proj"]), h_last
+    y = _gated_norm(y.reshape(Bsz, S, d_inner), z, p["norm_w"])
+    cache = MambaCache(_last_inputs(x_in, K), _last_inputs(bc_in, K), h_last)
+    return jnp.einsum("bse,ed->bsd", y, p["out_proj"]), cache
 
 
 def mamba2_decode(p, u, cache: MambaCache):
@@ -185,7 +202,6 @@ def mamba2_decode(p, u, cache: MambaCache):
     h_new = cache.h * decay[..., None, None] + \
         jnp.einsum("bn,bhp->bhnp", Bm, xdt)
     y = jnp.einsum("bn,bhnp->bhp", Cm, h_new) + p["D"][:, None] * x
-    y = y.reshape(Bsz, d_inner).astype(u.dtype)
-    y = rmsnorm(y, p["norm_w"]) * jax.nn.silu(z)
+    y = _gated_norm(y.reshape(Bsz, d_inner).astype(u.dtype), z, p["norm_w"])
     return jnp.einsum("be,ed->bd", y, p["out_proj"]), \
         MambaCache(wx[:, 1:, :], wbc[:, 1:, :], h_new)

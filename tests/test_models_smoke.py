@@ -2,12 +2,13 @@
 forward + one real train step on CPU, asserting shapes and no NaNs."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import ARCHS, get_smoke_config
 from repro.models import Model
 from repro.optim import AdamWConfig, adamw_init, adamw_update
-from repro.models.layers import pad_vocab
+from repro.models.layers import pad_vocab, rmsnorm
 
 B, S = 2, 32
 
@@ -54,8 +55,12 @@ def test_forward_and_train_step(arch):
                                   and get_smoke_config(a).input_mode == "tokens"])
 def test_prefill_decode_consistency(arch):
     """Greedy decode after prefill produces logits of the right shape and
-    valid (finite) values; cache pos advances."""
+    valid (finite) values; cache pos advances. For the pure state-space
+    family, prefill then decode through the cache equals the full forward
+    pass on logits."""
     cfg = get_smoke_config(arch)
+    if cfg.family == "ssm":
+        _check_ssm_prefill_decode_matches_forward(cfg)
     model = Model(cfg)
     params, _ = model.init(jax.random.PRNGKey(0))
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)
@@ -68,6 +73,36 @@ def test_prefill_decode_consistency(arch):
         logits, state = jax.jit(model.decode_step)(params, state, nxt)
         assert bool(jnp.isfinite(logits).all())
         nxt = jnp.argmax(logits[:, :cfg.vocab_size], -1).astype(jnp.int32)
+
+
+def _check_ssm_prefill_decode_matches_forward(cfg, n_decode=8):
+    """Prefill S tokens, then decode the next ``n_decode`` of the same
+    sequence one by one; each step's logits against the full forward pass
+    over all S + n_decode tokens at that position. In float32 at the
+    highest matmul precision, so the chunked scan and the recurrent update
+    differ by rounding only: 1e-4, relative to the logits' largest."""
+    from repro.models.model import _lm_logits, _ssm_backbone
+    cfg = cfg.replace(dtype=jnp.float32)
+    model = Model(cfg)
+    params, _ = model.init(jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (B, S + n_decode), 0,
+                              cfg.vocab_size)
+
+    def full(p, t):
+        h = _ssm_backbone(p, cfg, jnp.take(p["embed"], t, axis=0))
+        return _lm_logits(p, cfg, rmsnorm(h, p["final_norm"], cfg.norm_eps))
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(full)(params, toks)[:, S - 1:]
+        logits, state = jax.jit(lambda p, b: model.prefill(p, b, S))(
+            params, {"tokens": toks[:, :S]})
+        got = [logits]
+        for i in range(n_decode):
+            logits, state = jax.jit(model.decode_step)(params, state,
+                                                       toks[:, S + i])
+            got.append(logits)
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(np.asarray(jnp.stack(got, axis=1)),
+                               np.asarray(want), rtol=0, atol=1e-4 * scale)
 
 
 def test_full_configs_match_assignment():
