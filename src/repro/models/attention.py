@@ -2,10 +2,19 @@
 masks, full-sequence forward (train & prefill) and single-token decode
 against a (optionally rolling) KV cache.
 
-The full-sequence path can route through the Pallas flash-attention kernel
-(``cfg.use_flash``); the default XLA path is the lowering used by the
-dry-run/roofline (kernels target real TPUs and are validated separately in
-interpret mode).
+``multihead_attn`` takes one of four full-sequence paths:
+
+- ``use_flash``: the Pallas flash-attention kernel (kernels target real
+  TPUs and are validated separately in interpret mode);
+- otherwise, by shape and mask alone:
+  - ``S >= chunk_q_threshold`` (a multiple of ``chunk_q``):
+    ``_chunked_attn``, a scan over query chunks that bounds the score
+    temporary at long sequences;
+  - causal with ``S`` a multiple of ``CAUSAL_CHUNK`` and at least two
+    chunks: ``_causal_chunked_attn``, where each query chunk attends only
+    to the keys it can see, so the masked upper triangle is not computed;
+  - everything else (bidirectional, short sequences): ``_dense_attn``,
+    the lowering used by the dry-run/roofline.
 """
 from __future__ import annotations
 
@@ -16,6 +25,12 @@ import jax
 import jax.numpy as jnp
 
 from .layers import _init, apply_rope
+
+# Query chunk of the causal key-truncated path: chunk i attends to keys
+# [lo_i, (i+1) * CAUSAL_CHUNK), so about (n+1)/(2n) of the S x S scores
+# are computed for n chunks. On a TPU v5e, training SmolLM-360M at
+# 4 x 2048, 256 ran about 20% faster than 512.
+CAUSAL_CHUNK = 256
 
 
 def attn_init(rng, d_model, n_heads, n_kv, head_dim, dtype):
@@ -45,17 +60,49 @@ def _mask(q_pos, k_pos, causal: bool, window: int):
     return m
 
 
-def _dense_attn(q, k, v, positions, causal, window):
-    """Materialises the full (S, S) score matrix — short sequences only."""
-    B, S, KV, hd = k.shape
-    H = q.shape[2]
+def _dense_attn(q, k, v, q_pos, k_pos, causal, window):
+    """Materialises the full (Sq, Sk) score matrix, masked entries included.
+    Taken for bidirectional attention and for causal sequences shorter than
+    two ``CAUSAL_CHUNK``s or not a multiple of it; ``_causal_chunked_attn``
+    runs it per query chunk over the keys that chunk can see."""
+    B, Sq, H, hd = q.shape
+    KV = k.shape[2]
     G = H // KV
-    qg = q.reshape(B, S, KV, G, hd)
+    qg = q.reshape(B, Sq, KV, G, hd)
     scores = jnp.einsum("bskgh,btkh->bkgst", qg, k) / math.sqrt(hd)
-    mask = _mask(positions, positions, causal, window)      # (B, S, S)
+    mask = _mask(q_pos, k_pos, causal, window)              # (B, Sq, Sk)
     scores = jnp.where(mask[:, None, None], scores.astype(jnp.float32), -1e9)
     w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bkgst,btkh->bskgh", w, v).reshape(B, S, H, hd)
+    return jnp.einsum("bkgst,btkh->bskgh", w, v).reshape(B, Sq, H, hd)
+
+
+def causal_key_range(i, chunk, window):
+    """Keys [lo, hi) that query chunk ``i`` can see under a causal mask
+    (and a sliding window of ``window`` positions, 0 for none), with ``lo``
+    rounded down to a multiple of ``chunk``."""
+    lo = max(0, i * chunk - window + 1) if window else 0
+    return lo - lo % chunk, (i + 1) * chunk
+
+
+def _causal_chunked_attn(q, k, v, positions, window, chunk):
+    """Causal attention without the masked upper triangle: query chunk i
+    is ``_dense_attn`` over only the keys ``causal_key_range`` gives it.
+    The chunks differ in key length, so they are a Python loop and not a
+    scan; autodiff differentiates it as it stands.
+
+    Exact only where positions increase strictly along the sequence, as
+    every caller's ``arange(S)`` does: the keys left out are then the ones
+    the mask would have given weight zero.
+    """
+    outs = []
+    with jax.named_scope("attn_causal_chunks"):
+        for i in range(q.shape[1] // chunk):
+            lo, hi = causal_key_range(i, chunk, window)
+            qs = slice(i * chunk, hi)
+            outs.append(_dense_attn(q[:, qs], k[:, lo:hi], v[:, lo:hi],
+                                    positions[:, qs], positions[:, lo:hi],
+                                    True, window))
+        return jnp.concatenate(outs, axis=1)
 
 
 def _chunked_attn(q, k, v, positions, causal, window, chunk_q):
@@ -100,8 +147,10 @@ def multihead_attn(p, x, positions, *, causal=True, window=0, rope_theta=1e4,
                                       block_q=flash_block, block_k=flash_block)
     elif S >= chunk_q_threshold and S % chunk_q == 0:
         o = _chunked_attn(q, k, v, positions, causal, window, chunk_q)
+    elif causal and S % CAUSAL_CHUNK == 0 and S >= 2 * CAUSAL_CHUNK:
+        o = _causal_chunked_attn(q, k, v, positions, window, CAUSAL_CHUNK)
     else:
-        o = _dense_attn(q, k, v, positions, causal, window)
+        o = _dense_attn(q, k, v, positions, positions, causal, window)
     return jnp.einsum("bshk,hkd->bsd", o, p["o"])
 
 
