@@ -2,8 +2,8 @@
 
     PYTHONPATH=src:. python -m repro.profile benchmarks/_profile_target.py
 
-Used to produce the pre/post hot-spot tables for the scale work
-(docs/scale.md); takes --n and --trace like run_workload.
+Profiles the simulator hot path (docs/observability.md, "Profiling the
+simulator hot path"); takes --n and --trace like run_workload.
 """
 import argparse
 

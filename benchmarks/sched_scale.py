@@ -15,28 +15,22 @@ Two claims, one JSON:
   deadline; if it blows through the deadline the recorded speedup is the
   proven lower bound.
 
-ISSUE 10 additions (sharded control plane, docs/scale.md):
+Also:
 
-* ``--shards N`` runs the same workload through the ShardedScheduler with
-  per-chain ``shard_key`` anchors and records the sharded wall clock —
-  the 1M-task < 60 s headline run is
-  ``--n-tasks 1000000 --shards 4 --check-regress`` (``--check-regress``
-  exits non-zero when the sharded leg misses ``--deadline``, default 60 s).
-  The seed comparison is skipped above ``--seed-max-n`` (the O(ready^2)
-  seed would need hours there; the 100k default already proves the bound).
+* The seed comparison is skipped above ``SEED_MAX_N`` tasks (the
+  O(ready^2) seed would need hours there; the 100k default already proves
+  the bound). ``--check-regress`` exits non-zero when the scale leg misses
+  ``--deadline`` seconds of wall clock (default 60).
 * **Traced-overhead pin** — the memoized blocked-head diagnosis keeps a
   traced run within ``TRACED_RATIO_MAX`` x of the untraced wall clock on
   the same workload (before memoization a traced contended run re-walked
   every worker per round); asserted on every invocation.
-* ``--parity --shards N`` runs the symmetric lockstep DAG (full-worker
-  compute chains + locality-anchored checkpoints) at shards 1 and N and
-  asserts bit-identical launch logs — the CI 2-shard golden-parity smoke.
 
 Usage::
 
     PYTHONPATH=src python -m benchmarks.sched_scale \
-        [--n-tasks 100000] [--golden-n 1000] [--shards 1] \
-        [--check-regress] [--deadline 60] [--parity] \
+        [--n-tasks 100000] [--golden-n 1000] \
+        [--check-regress] [--deadline 60] \
         [--out BENCH_sched_scale.json]
 """
 from __future__ import annotations
@@ -95,21 +89,13 @@ def _make_cluster() -> Cluster:
 
 
 def run_workload(n_tasks: int, scheduler_cls=Scheduler, backend=None,
-                 trace=False, shards: int = 1, n_workers: int = 0):
+                 trace=False):
     """Mixed compute/I/O workload: compute stages feeding static- and
     auto-constrained checkpoints (deterministic durations/sizes).
     ``trace=True`` wires an obs TraceRecorder (the determinism tests use
-    this to pin that tracing never perturbs the launch log).
-    ``shards > 1`` runs the sharded control plane with per-chain
-    ``shard_key`` anchors (shards == 1 passes no shard kwargs at all, so
-    the golden comparison workload stays byte-identical to the seed's)."""
+    this to pin that tracing never perturbs the launch log)."""
     _reset_ids()
-    # n_workers=0 keeps the canonical 4-worker golden cluster; the sharded
-    # scale leg passes a wider cluster so each shard owns a BLOCK of
-    # workers (the scale-out shape the control plane is for) rather than
-    # a single worker per shard
-    cluster = _make_cluster() if not n_workers else \
-        Cluster.make(n_workers=n_workers, cpus=8, io_executors=32)
+    cluster = _make_cluster()
     backend = backend or SimBackend()
 
     @task(returns=1)
@@ -130,55 +116,14 @@ def run_workload(n_tasks: int, scheduler_cls=Scheduler, backend=None,
 
     t0 = time.perf_counter()
     with _gc_quiesced(), IORuntime(cluster, backend=backend,
-                                   scheduler_cls=scheduler_cls, trace=trace,
-                                   shards=shards) as rt:
+                                   scheduler_cls=scheduler_cls,
+                                   trace=trace) as rt:
         for i in range(n_tasks // 2):
-            if shards > 1:
-                r = stage(i, duration=1.0 + (i % 7) * 0.25, shard_key=i)
-                if i % 3 == 2:
-                    ck_auto(r, i, io_mb=40.0, shard_key=i)
-                else:
-                    ck_static(r, i, io_mb=40.0, shard_key=i)
+            r = stage(i, duration=1.0 + (i % 7) * 0.25)
+            if i % 3 == 2:
+                ck_auto(r, i, io_mb=40.0)
             else:
-                r = stage(i, duration=1.0 + (i % 7) * 0.25)
-                if i % 3 == 2:
-                    ck_auto(r, i, io_mb=40.0)
-                else:
-                    ck_static(r, i, io_mb=40.0)
-        rt.barrier(final=True)
-        elapsed = time.perf_counter() - t0
-        return rt.scheduler.launch_log, rt.stats(), elapsed
-
-
-def run_symmetric(n_chains: int, depth: int, shards: int = 1,
-                  n_workers: int = 4):
-    """Symmetric lockstep DAG for shard-count parity: full-worker compute
-    chains (uniform durations) feeding locality-placed static checkpoints,
-    each chain anchored by its own ``shard_key``. On this workload the
-    shard-confined placement IS the global first-fit placement, so launch
-    logs are bit-identical across shard counts (docs/scale.md)."""
-    _reset_ids()
-    cluster = Cluster.make(n_workers=n_workers, cpus=8, io_executors=32)
-    cluster.shared_workdir = False  # I/O follows producer locality
-
-    @constraint(computingUnits=8)
-    @task(returns=1)
-    def stage(x, i):
-        pass
-
-    @constraint(storageBW=8)
-    @io
-    @task()
-    def ck(x, i):
-        pass
-
-    t0 = time.perf_counter()
-    with _gc_quiesced(), IORuntime(cluster, shards=shards) as rt:
-        futs = [0] * n_chains
-        for _ in range(depth):
-            for i in range(n_chains):
-                futs[i] = stage(futs[i], i, duration=1.0, shard_key=i)
-                ck(futs[i], i, io_mb=40.0, shard_key=i)
+                ck_static(r, i, io_mb=40.0)
         rt.barrier(final=True)
         elapsed = time.perf_counter() - t0
         return rt.scheduler.launch_log, rt.stats(), elapsed
@@ -255,33 +200,6 @@ def scale_run(n_tasks: int = LARGE_N, seed_deadline_factor: float = 30.0,
     return out
 
 
-def shard_scale_run(n_tasks: int, shards: int,
-                    workers_per_shard: int = 4) -> dict:
-    """The sharded leg: same workload, shard_key-anchored chains, N-shard
-    control plane over a cluster where each shard owns a block of
-    ``workers_per_shard`` workers (the scale-out shape sharding models —
-    one worker per shard would measure confinement, not the control
-    plane). Reports wall clock plus the control-plane rollup (bus
-    counters, lease invariant check)."""
-    n_workers = shards * workers_per_shard
-    log, stats, new_s = run_workload(n_tasks, shards=shards,
-                                     n_workers=n_workers)
-    sh = stats.get("shards", {})
-    violations = sh.get("lease_violations", [])
-    assert not violations, f"lease invariants violated: {violations}"
-    return {
-        "n_tasks": n_tasks,
-        "shards": shards,
-        "n_workers": n_workers,
-        "n_launched": len(log),
-        "makespan": stats["makespan"],
-        "new_seconds": new_s,
-        "bus": sh.get("bus"),
-        "cross_shard_edges": sh.get("cross_shard_edges"),
-        "local_edges": sh.get("local_edges"),
-    }
-
-
 def traced_overhead(n_tasks: int = TRACED_N) -> dict:
     """Traced-vs-untraced pin for the memoized blocked-head diagnosis: a
     traced run of the contended workload must stay within
@@ -300,61 +218,17 @@ def traced_overhead(n_tasks: int = TRACED_N) -> dict:
             "budget": TRACED_RATIO_MAX}
 
 
-def shard_parity(shards: int, n_chains: int = 16, depth: int = 5) -> dict:
-    """CI golden-parity smoke: the symmetric lockstep DAG must produce the
-    same launch log at 1 shard and at ``shards`` shards."""
-    log1, stats1, _ = run_symmetric(n_chains, depth, shards=1)
-    logn, statsn, _ = run_symmetric(n_chains, depth, shards=shards)
-    if log1 != logn:
-        diff = next(((i, a, b) for i, (a, b)
-                     in enumerate(zip(log1, logn)) if a != b),
-                    "one log is a prefix of the other")
-        raise AssertionError(
-            f"shard parity broken at shards={shards}: first divergence "
-            f"{diff} (lens {len(log1)}/{len(logn)})")
-    assert stats1["makespan"] == statsn["makespan"]
-    return {"shards": shards, "n_launched": len(log1),
-            "identical_launch_log": True, "makespan": stats1["makespan"]}
-
-
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", "--n-tasks", dest="n", type=int, default=LARGE_N)
     ap.add_argument("--golden-n", type=int, default=GOLDEN_N)
-    ap.add_argument("--shards", type=int, default=1)
     ap.add_argument("--check-regress", action="store_true",
-                    help="exit non-zero when the (sharded) scale leg "
-                         "misses --deadline seconds of wall clock")
+                    help="exit non-zero when the scale leg misses "
+                         "--deadline seconds of wall clock")
     ap.add_argument("--deadline", type=float, default=60.0)
-    ap.add_argument("--parity", action="store_true",
-                    help="only run the symmetric shard-parity smoke "
-                         "(CI fast tier)")
     ap.add_argument("--out", default="BENCH_sched_scale.json")
     args = ap.parse_args(argv)
 
-    if args.parity:
-        shards = args.shards if args.shards > 1 else 2
-        parity = shard_parity(shards)
-        print(f"parity @ shards={shards}: {parity['n_launched']} launches "
-              f"bit-identical to shards=1 (makespan {parity['makespan']})")
-        report = write_report(
-            args.out, {"parity": parity}, bench="sched_scale_parity",
-            config={"shards": shards},
-            headline_metric=("parity_n_launched", parity["n_launched"],
-                             "max"))
-        print(f"wrote {args.out}")
-        return report
-
-    # the sharded headline leg runs FIRST: wall-clock at the 1M scale is
-    # sensitive to allocator/heap history, and the deadline-checked leg
-    # deserves the fresh heap rather than one fragmented by the golden,
-    # traced and unsharded legs that precede it logically
-    shard = None
-    if args.shards > 1:
-        shard = shard_scale_run(args.n, args.shards)
-        print(f"sharded @ {args.n} x {args.shards} shards: "
-              f"{shard['new_seconds']:.2f}s "
-              f"(cross-shard edges {shard['cross_shard_edges']})")
     golden = golden_compare(args.golden_n)
     print(f"golden @ {args.golden_n}: launch_log + stats identical "
           f"(seed {golden['seed_seconds']:.2f}s, new {golden['new_seconds']:.2f}s)")
@@ -373,24 +247,17 @@ def main(argv=None) -> dict:
         print(f"scale @ {args.n}: new {scale['new_seconds']:.2f}s "
               f"(seed comparison skipped above {SEED_MAX_N})")
     results = {"golden": golden, "scale": scale, "traced": traced}
-    headline = ("scale_new_seconds", scale["new_seconds"], "min")
-    if shard is not None:
-        results["shard_scale"] = shard
-        headline = ("shard_scale_new_seconds", shard["new_seconds"], "min")
     report = write_report(
         args.out, results, bench="sched_scale",
-        config={"n": args.n, "golden_n": args.golden_n,
-                "shards": args.shards},
-        headline_metric=headline)
+        config={"n": args.n, "golden_n": args.golden_n},
+        headline_metric=("scale_new_seconds", scale["new_seconds"], "min"))
     print(f"wrote {args.out}")
     if args.check_regress:
-        budget_leg = results.get("shard_scale", scale)
-        if budget_leg["new_seconds"] > args.deadline:
+        if scale["new_seconds"] > args.deadline:
             raise SystemExit(
-                f"REGRESSION: scale leg took "
-                f"{budget_leg['new_seconds']:.2f}s "
+                f"REGRESSION: scale leg took {scale['new_seconds']:.2f}s "
                 f"> deadline {args.deadline:.0f}s")
-        print(f"check-regress: {budget_leg['new_seconds']:.2f}s "
+        print(f"check-regress: {scale['new_seconds']:.2f}s "
               f"<= {args.deadline:.0f}s deadline")
     return report
 

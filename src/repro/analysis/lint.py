@@ -20,8 +20,6 @@ code       category         condition
 ``IO401``  determinism      unseeded ``BurstyTraffic`` (irreproducible runs)
 ``IO402``  determinism      task body references an unseeded RNG source
 ``IO501``  failure-domains  schedule leaves the durable tier offline forever
-``IO601``  sharding         dependency chain ping-pongs across shard anchors
-``IO602``  sharding         shared-tier output fanned out to many shard anchors
 =========  ===============  ====================================================
 
 Feasibility predicates are shared with the scheduler
@@ -43,7 +41,7 @@ from ..core.scheduler import eligible_devices
 from ..core.task import TaskInstance, TaskType
 
 CATEGORIES = {"1": "constraints", "2": "capacity", "3": "race/ordering",
-              "4": "determinism", "5": "failure-domains", "6": "sharding"}
+              "4": "determinism", "5": "failure-domains"}
 
 _MOVER_SIGS = ("tier_drain", "tier_prefetch")
 
@@ -520,104 +518,6 @@ def _rule_io501_durable_tier_killed(ctx: _Ctx) -> Iterator[Diagnostic]:
             f"to land — add a recovery event or pick another durable_tier")
 
 
-# --------------------------------------------------------------------------
-# IO6xx — sharding (core.shardplane, docs/scale.md)
-# --------------------------------------------------------------------------
-def _shared_tier_names(cluster) -> set:
-    """Tiers backed by a device that two or more workers reference — the
-    lease-brokered cross-shard resources (per-worker SSDs never qualify).
-    Matches :func:`repro.core.shardplane.shared_devices` for any shard
-    count >= 2, so the diagnostics are shard-count-agnostic."""
-    refs: dict[int, int] = {}
-    tier_of: dict[int, Optional[str]] = {}
-    for w in cluster.workers:
-        for dev in w.tiers:
-            refs[id(dev)] = refs.get(id(dev), 0) + 1
-            tier_of[id(dev)] = dev.tier
-    return {tier_of[i] for i, n in refs.items() if n > 1}
-
-
-def _rule_io601_shard_pingpong(ctx: _Ctx) -> Iterator[Diagnostic]:
-    """A dependency chain whose ``shard_key=`` anchors alternate workers:
-    under any shard count that separates those anchor workers, every edge
-    of the chain is a cross-shard DEP_DONE message and the consumer's
-    placement loses its producer's locality. Anchors are compared at the
-    *worker* level (``key % n_workers``), which is what makes the finding
-    independent of the shard count the plan eventually runs with."""
-    from ..core.shardplane import anchor_worker  # lazy: keep lint importable
-    n_workers = len(ctx.cluster.workers)
-    if n_workers < 2:
-        return
-    by_tid = {t.tid: t for t in ctx.tasks}
-    seen = set()
-    for t in ctx.tasks:
-        if t.shard_key is None:
-            continue
-        a = anchor_worker(t.shard_key, n_workers)
-        for ptid in ctx.future_inputs.get(t.tid, ()):
-            p = by_tid.get(ptid)
-            if p is None or p.shard_key is None:
-                continue
-            pa = anchor_worker(p.shard_key, n_workers)
-            if pa == a:
-                continue
-            key = (p.defn.signature, t.defn.signature)
-            if key in seen:
-                continue
-            seen.add(key)
-            yield _diag(
-                "IO601",
-                f"shard_key={t.shard_key!r} anchors this task to worker "
-                f"{a} but its producer {p.defn.signature}#{p.tid} is "
-                f"anchored to worker {pa} (shard_key={p.shard_key!r}): the "
-                f"chain ping-pongs across shards — every edge becomes a "
-                f"cross-shard message and placement loses producer "
-                f"locality; use one shard_key along a dependency chain", t)
-
-
-def _rule_io602_shared_tier_fanout(ctx: _Ctx) -> Iterator[Diagnostic]:
-    """An I/O task pinned to a *shared* tier (burst buffer / shared FS)
-    whose readers are anchored to two or more distinct workers: its output
-    object's residency updates broadcast to every shard, and all reader
-    shards contend for the one lease-brokered device. Often intended —
-    shared tiers are the designed cross-shard channel — but worth flagging
-    when a per-worker tier would do."""
-    from ..core.shardplane import anchor_worker  # lazy: keep lint importable
-    n_workers = len(ctx.cluster.workers)
-    if n_workers < 2:
-        return
-    shared = _shared_tier_names(ctx.cluster)
-    if not shared:
-        return
-    reader_anchors: dict[int, set] = {}   # producer tid -> anchor workers
-    for t in ctx.tasks:
-        if t.shard_key is None:
-            continue
-        a = anchor_worker(t.shard_key, n_workers)
-        for ptid in ctx.future_inputs.get(t.tid, ()):
-            reader_anchors.setdefault(ptid, set()).add(a)
-    seen = set()
-    for t in ctx.io_tasks():
-        if t.tier not in shared:
-            continue
-        anchors = reader_anchors.get(t.tid, ())
-        if len(anchors) < 2:
-            continue
-        sig = t.defn.signature
-        if sig in seen:
-            continue
-        seen.add(sig)
-        yield _diag(
-            "IO602",
-            f"output pinned to shared tier {t.tier!r} is read by tasks "
-            f"anchored to {len(anchors)} distinct workers "
-            f"({sorted(anchors)}): every reader shard contends for the "
-            f"one lease-brokered device and the object's residency "
-            f"updates broadcast to all shards — expected for a designed "
-            f"cross-shard exchange, otherwise keep the chain on one "
-            f"shard_key or a per-worker tier", t)
-
-
 _RULES = (
     _rule_io101_static_bw, _rule_io102_unknown_tier, _rule_io103_cpu_units,
     _rule_io104_auto_min,
@@ -627,7 +527,6 @@ _RULES = (
     _rule_io303_payloadless_mover, _rule_io304_manifest_order,
     _rule_io401_unseeded_bursts, _rule_io402_rng_in_body,
     _rule_io501_durable_tier_killed,
-    _rule_io601_shard_pingpong, _rule_io602_shared_tier_fanout,
 )
 
 

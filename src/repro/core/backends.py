@@ -113,24 +113,9 @@ class SimBackend(Backend):
         self._launch_seq = itertools.count()                # seed-order pop ties
         self._dev_tasks: dict[int, tuple] = {}   # id(dev) -> (dev, set[tid])
         self._dev_epoch_seen: dict[int, int] = {}  # id(dev) -> release_epoch
-        # sharded control plane (core.shardplane): one event heap per shard,
-        # batch-scanned per event step so each shard's queue stays short.
-        # bind() splits the heaps when the runtime's scheduler is sharded;
-        # unsharded, _heaps[0] IS _heap (the same list object) and every
-        # push/scan/pop runs the exact arithmetic above — launch logs stay
-        # bit-identical.
-        self._heaps: list[list] = [self._heap]
-        self._tid_shard: Optional[dict[int, int]] = None  # tid -> shard
 
     def now(self) -> float:
         return self.clock
-
-    def bind(self, runtime) -> None:
-        super().bind(runtime)
-        n = getattr(runtime.scheduler, "n_shards", 1)
-        if n > 1:
-            self._heaps = [[] for _ in range(n)]
-            self._tid_shard = {}
 
     def attach_interference(self, engine) -> None:
         """Bind an InterferenceEngine: burst boundaries become simulation
@@ -157,9 +142,7 @@ class SimBackend(Backend):
     def _push_entry(self, tid: int, est: float) -> None:
         ver = self._entry_ver.get(tid, 0) + 1
         self._entry_ver[tid] = ver
-        heap = self._heap if self._tid_shard is None \
-            else self._heaps[self._tid_shard[tid]]
-        heapq.heappush(heap, (est, next(self._push_seq), tid, ver))
+        heapq.heappush(self._heap, (est, next(self._push_seq), tid, ver))
 
     def _true_finish(self, rec: list) -> float:
         task, rem, min_end = rec
@@ -195,8 +178,7 @@ class SimBackend(Backend):
             io = self._io
             ver_map = self._entry_ver
             push_seq = self._push_seq
-            tid_shard = self._tid_shard
-            heaps = self._heaps
+            heap = self._heap
             heappush = heapq.heappush
             inf = float("inf")
             for tid in tids:
@@ -210,15 +192,11 @@ class SimBackend(Backend):
                     est = inf
                 ver = ver_map.get(tid, 0) + 1
                 ver_map[tid] = ver
-                heap = heaps[0] if tid_shard is None \
-                    else heaps[tid_shard[tid]]
                 heappush(heap, (est, next(push_seq), tid, ver))
 
     def launch(self, task: TaskInstance, worker) -> None:
         task.start_time = self.clock
         task._sim_seq = next(self._launch_seq)
-        if self._tid_shard is not None:
-            self._tid_shard[task.tid] = task.shard
         if self.sanitizer is not None:
             self.sanitizer.record(
                 "launch", t=self.clock, tid=task.tid,
@@ -249,18 +227,10 @@ class SimBackend(Backend):
             self._push_entry(task.tid, self._true_finish(rec))
 
     def _next_event_time(self) -> float:
-        best = float("inf")
-        for heap in self._heaps:
-            t = self._scan_heap(heap)
-            if t < best:
-                best = t
-        return best
-
-    def _scan_heap(self, heap: list) -> float:
-        """Exact next event time within one shard's heap (the whole queue,
-        unsharded). The global next event is the min across shards — each
-        scan pops candidates within ``_GUARD`` of its own best, recomputes
-        their true finish at the current clock, and re-pushes."""
+        """Exact next event time: pop the candidates within ``_GUARD`` of
+        the best estimate, recompute their true finish at the current
+        clock, and re-push them."""
+        heap = self._heap
         ver = self._entry_ver
         best = float("inf")
         repush = []
@@ -340,8 +310,6 @@ class SimBackend(Backend):
     def _finish_io(self, tid: int) -> TaskInstance:
         task, _, _ = self._io.pop(tid)
         self._entry_ver.pop(tid, None)
-        if self._tid_shard is not None:
-            self._tid_shard.pop(tid, None)
         dev = task.device or task.worker.storage
         self._dev_tasks[id(dev)][1].discard(tid)
         return task
@@ -351,24 +319,7 @@ class SimBackend(Backend):
         due_io: list[TaskInstance] = []
         repush: list[tuple[int, float]] = []
         horizon = self.clock + _EPS
-        for heap in self._heaps:
-            self._pop_due_heap(heap, horizon, due_c, due_io, repush)
-        # re-push AFTER draining the horizon: a tightened estimate can land
-        # back inside it (fast devices: rem in MB vs horizon in seconds) and
-        # re-pushing inside the loop would pop it again forever
-        for tid, est in repush:
-            self._push_entry(tid, est)
-        # the seed popped compute tasks then I/O tasks, each in launch order
-        # (the per-shard batches merge into the same global order: _sim_seq
-        # is assigned from one counter at launch)
-        due_c.sort(key=lambda t: t._sim_seq)
-        due_io.sort(key=lambda t: t._sim_seq)
-        return due_c + due_io
-
-    def _pop_due_heap(self, heap: list, horizon: float,
-                      due_c: list, due_io: list, repush: list) -> None:
-        """Drain one shard's heap up to ``horizon`` into the shared due
-        batches (the whole event queue, unsharded)."""
+        heap = self._heap
         ver = self._entry_ver
         while heap and heap[0][0] <= horizon:
             _, _, tid, v = heapq.heappop(heap)
@@ -379,8 +330,6 @@ class SimBackend(Backend):
                 if end <= horizon:
                     del self._compute[tid]
                     del ver[tid]
-                    if self._tid_shard is not None:
-                        self._tid_shard.pop(tid, None)
                     due_c.append(task)
                 else:  # defensive: estimate undershot the fixed end
                     repush.append((tid, end))
@@ -390,6 +339,15 @@ class SimBackend(Backend):
                     due_io.append(self._finish_io(tid))
                 else:  # estimate was early (device gained streams): tighten
                     repush.append((tid, self._true_finish(rec)))
+        # re-push AFTER draining the horizon: a tightened estimate can land
+        # back inside it (fast devices: rem in MB vs horizon in seconds) and
+        # re-pushing inside the loop would pop it again forever
+        for tid, est in repush:
+            self._push_entry(tid, est)
+        # the seed popped compute tasks then I/O tasks, each in launch order
+        due_c.sort(key=lambda t: t._sim_seq)
+        due_io.sort(key=lambda t: t._sim_seq)
+        return due_c + due_io
 
     # ------------------------------------------------------ failure domains
     def _fail_attempt(self, task: TaskInstance, error: BaseException) -> bool:

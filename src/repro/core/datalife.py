@@ -254,14 +254,6 @@ class DataCatalog:
         # trace recorder (obs/): wired by the runtime when tracing is on;
         # None costs one comparison per lifecycle event
         self.recorder = None
-        # sharded control plane (core.shardplane): the ShardedScheduler
-        # wires its bus here so every residency change is ALSO posted as an
-        # ordered broadcast message. The mutation itself stays synchronous
-        # (applied before the message is posted, never partially): eviction
-        # planning and read-penalty snapshots run between bus drains and
-        # must see current occupancy — the message stream is the ordered
-        # cross-shard record, not the mechanism of the update.
-        self.shardbus = None
         self._tier_order = cluster.tier_names()
         self._rank = {t: i for i, t in enumerate(self._tier_order)}
         # apply TierCapacity budgets before auto-detection
@@ -363,29 +355,14 @@ class DataCatalog:
                     out.append(obj)
         return out
 
-    def _shard_of(self, obj: DataObject) -> int:
-        """Source shard of a residency message: the producing task's owner
-        (external objects and pre-shard producers fall back to shard 0)."""
-        if self.graph is not None:
-            t = self.graph.tasks.get(obj.producer_tid)
-            if t is not None:
-                return t.shard
-        return 0
-
     def _add_residency(self, obj: DataObject, dev: StorageDevice) -> None:
         obj.residency[dev.tier] = dev
         self._resident.setdefault(id(dev), set()).add(obj)
-        if self.shardbus is not None:
-            self.shardbus.post("RESIDENCY_ADD", self._shard_of(obj), None,
-                               (obj.name, dev.tier))
 
     def _drop_residency(self, obj: DataObject, dev: StorageDevice) -> None:
         if obj.residency.get(dev.tier) is dev:
             del obj.residency[dev.tier]
         self._resident.get(id(dev), set()).discard(obj)
-        if self.shardbus is not None:
-            self.shardbus.post("RESIDENCY_DROP", self._shard_of(obj), None,
-                               (obj.name, dev.tier))
 
     # ----------------------------------------------------------- ingestion
     def add_external(self, name: str, size_mb: float, tier: str,

@@ -103,14 +103,6 @@ class TaskGraph:
         self.tasks: dict[int, TaskInstance] = {}
         self.unfinished: int = 0
         self._missing_deps: dict[int, int] = {}  # tid -> #unfinished deps
-        # sharded control plane (core.shardplane): when the runtime routes
-        # tasks to shards it flips track_shards so every edge is classified
-        # at add() time — cross-shard edges are the dependency messages the
-        # ShardBus will carry (already-DONE producers included: the edge
-        # crossed the boundary even if it never blocked anything)
-        self.track_shards = False
-        self.cross_shard_edges = 0
-        self.local_edges = 0
 
     def add(self, task: TaskInstance) -> bool:
         """Register a task; returns True if it is immediately ready.
@@ -124,13 +116,6 @@ class TaskGraph:
         pairs = bind_args(task)  # bound once, shared by both passes
         deps = compute_deps(task, pairs)  # dep -> is_data (data wins)
         apply_handle_effects(task, pairs)
-        if self.track_shards:
-            shard = task.shard
-            for d in deps:
-                if d.shard == shard:
-                    self.local_edges += 1
-                else:
-                    self.cross_shard_edges += 1
 
         task.deps = set()
         task.anti_deps = set()

@@ -76,7 +76,7 @@ def current_runtime() -> Optional["IORuntime"]:
 #: wrapped function must not declare parameters with these names, because
 #: the runtime strips them before the user function runs.
 RESERVED_KWARGS = ("io_mb", "duration", "storage_bw", "storage_tier",
-                   "sim_fail", "shard_key")
+                   "sim_fail")
 
 
 class TaskFunction:
@@ -105,7 +105,6 @@ class TaskFunction:
         bw_override = pop("storage_bw", None)
         storage_tier = pop("storage_tier", None)
         fail_spec = pop("sim_fail", None)
-        shard_key = pop("shard_key", None)
         io_mb = float(raw_io_mb) if raw_io_mb else 0.0
         duration = float(raw_duration) if raw_duration else 0.0
         if io_mb < 0:
@@ -129,8 +128,7 @@ class TaskFunction:
         return rt.submit(self.defn, args, kwargs, sim,
                          storage_bw=parse_storage_bw(bw_override)
                          if bw_override is not None else None,
-                         storage_tier=storage_tier,
-                         shard_key=shard_key)
+                         storage_tier=storage_tier)
 
 
 def _as_taskfn(fn) -> TaskFunction:
@@ -287,18 +285,15 @@ class IORuntime:
                  failures=None,
                  drift: Optional[DriftConfig] = None,
                  tier_objective: bool = False,
-                 trace=False,
-                 shards: int = 1):
+                 trace=False):
         self.cluster = cluster
-        self.n_shards = int(shards)
         # constructor config, replayed by rt.plan() to build the capture
         # sibling with the same lifecycle/interference/tuning setup
         self._plan_config = dict(scheduler_cls=scheduler_cls,
                                  lifecycle=lifecycle,
                                  interference=interference,
                                  failures=failures, drift=drift,
-                                 tier_objective=tier_objective,
-                                 shards=shards)
+                                 tier_objective=tier_objective)
         if isinstance(backend, str):
             if backend == "capture":
                 from ..analysis.capture import CaptureBackend  # lazy: cycle
@@ -328,19 +323,7 @@ class IORuntime:
         self.backend = backend
         self.lock = threading.RLock()
         self.graph = TaskGraph()
-        # sharded control plane (shardplane.py, docs/scale.md): shards > 1
-        # partitions the workers into per-shard schedulers behind the
-        # ShardedScheduler facade; shards == 1 keeps the plain Scheduler —
-        # zero facade overhead, bit-identical to every prior release
-        if self.n_shards > 1:
-            from .shardplane import ShardedScheduler  # lazy: rarely taken
-            self.scheduler = ShardedScheduler(
-                cluster, launch=self.backend.launch,
-                n_shards=self.n_shards, scheduler_cls=scheduler_cls)
-            self.graph.track_shards = True
-        else:
-            self.scheduler = scheduler_cls(cluster,
-                                           launch=self.backend.launch)
+        self.scheduler = scheduler_cls(cluster, launch=self.backend.launch)
         if drift is not None or tier_objective:
             set_tuning = getattr(self.scheduler, "set_tuning", None)
             if set_tuning is not None:
@@ -461,7 +444,7 @@ class IORuntime:
 
     # ------------------------------------------------------------- submission
     def submit(self, defn: TaskDef, args, kwargs, sim: SimSpec,
-               storage_bw=None, storage_tier=None, shard_key=None):
+               storage_bw=None, storage_tier=None):
         traced = _spans.enabled()
         with _spans.locked(self.lock, traced):
             if self.capture_mode:
@@ -474,8 +457,6 @@ class IORuntime:
                 inst = TaskInstance(defn, args, kwargs, sim=sim,
                                     storage_bw=storage_bw,
                                     storage_tier=storage_tier)
-                if shard_key is not None:
-                    inst.shard_key = shard_key  # lint reads routing anchors
                 inst.submit_time = 0.0
                 self.backend.capture.on_submit(inst)
                 ready = self.graph.add(inst)
@@ -489,13 +470,6 @@ class IORuntime:
             inst = TaskInstance(defn, args, kwargs, sim=sim,
                                 storage_bw=storage_bw,
                                 storage_tier=storage_tier)
-            if shard_key is not None:
-                inst.shard_key = shard_key
-            if self.n_shards > 1:
-                # route once, at submission: the owning shard is fixed for
-                # the task's lifetime (validate_submit below checks the
-                # class against that shard's sub-cluster)
-                inst.shard = self.scheduler.route(inst)
             # reject unsatisfiable constraint/tier classes HERE, before the
             # task enters the graph: the error surfaces at the call site and
             # no half-registered state (unfinished counts, dependents) is
@@ -933,8 +907,7 @@ class IORuntime:
                         interference=cfg["interference"],
                         failures=cfg["failures"],
                         drift=cfg["drift"],
-                        tier_objective=cfg["tier_objective"],
-                        shards=cfg["shards"])
+                        tier_objective=cfg["tier_objective"])
         prev = getattr(_current, "rt", None)
         _current.rt = prt
         try:
@@ -968,13 +941,6 @@ class IORuntime:
                                  "peak_occupancy_mb": d.peak_occupancy_mb}
                         for d in self.cluster.devices},
         }
-        if getattr(self.scheduler, "n_shards", 1) > 1:
-            # sharded control plane rollup: per-shard launch counts, bus
-            # message counters, lease accounts. Present exactly when the
-            # run was sharded — unsharded stats stay schema-identical.
-            out["shards"] = self.scheduler.summary()
-            out["shards"]["cross_shard_edges"] = self.graph.cross_shard_edges
-            out["shards"]["local_edges"] = self.graph.local_edges
         if self.catalog.enabled:
             out["lifecycle"] = self.catalog.summary()
         if self.interference is not None:

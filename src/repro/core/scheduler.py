@@ -124,12 +124,6 @@ class Scheduler:
         # a disabled run pays one is-not-None check per readiness/refusal
         self.recorder = None
         self.capacity_blocked: dict[int, float] = {}  # id(dev) -> wanted MB
-        # sharded control plane (shardplane.py): this scheduler's identity
-        # inside a ShardedScheduler, and the lease broker gating bandwidth
-        # grants on shared devices. The unsharded defaults cost one is-None
-        # check per I/O grant and nothing else.
-        self.shard_id = 0
-        self.shard_lease = None
         # tuning extensions (interference.py / autotune.DriftConfig): both
         # default off, leaving the paper's placement byte-identical
         self.drift_config: Optional[DriftConfig] = None
@@ -410,9 +404,7 @@ class Scheduler:
     def _attempt_head(self, key: tuple) -> bool:
         """One placement attempt on the head of class ``key`` (no re-queue):
         True launched and dequeued it; False leaves the class blocked for
-        the rest of the round. The sharded control plane
-        (shardplane.ShardedScheduler) calls this directly so its global
-        round can interleave class heads across shards in readiness order."""
+        the rest of the round (and, traced, records why)."""
         q = self._ready_q[key]
         task = q[0]
         if self._try_place(task):
@@ -589,9 +581,6 @@ class Scheduler:
             return False
         if not self._capacity_ok(task, dev):
             return False
-        if bw > 0 and self.shard_lease is not None \
-                and not self.shard_lease.acquire(self.shard_id, dev, bw):
-            return False
         w.free_io_executors -= 1
         if bw >= 0:
             dev.allocate(bw)
@@ -616,15 +605,7 @@ class Scheduler:
             return False
         if not self._capacity_ok(task, dev):
             return False
-        # lease before admit: un-admitting is observable tuner state, so the
-        # lease (pure accounting, and always grantable after can_allocate —
-        # see shardplane.LeaseBroker) is the one taken tentatively
-        if c > 0 and self.shard_lease is not None \
-                and not self.shard_lease.acquire(self.shard_id, dev, c):
-            return False
         if not tuner.admit():
-            if c > 0 and self.shard_lease is not None:
-                self.shard_lease.release(self.shard_id, dev, c)
             return False  # current epoch full; wait for the next one
         node.free_io_executors -= 1
         dev.allocate(c)
@@ -649,9 +630,6 @@ class Scheduler:
             if w.free_io_executors <= 0 or not dev.can_allocate(c):
                 continue
             if not self._capacity_ok(task, dev):
-                continue
-            if c > 0 and self.shard_lease is not None \
-                    and not self.shard_lease.acquire(self.shard_id, dev, c):
                 continue
             w.free_io_executors -= 1
             dev.allocate(c)
@@ -794,8 +772,6 @@ class Scheduler:
             w.free_io_executors += 1
             dev = task.device or w.storage
             dev.release(task.granted_bw)
-            if self.shard_lease is not None and task.granted_bw > 0:
-                self.shard_lease.release(self.shard_id, dev, task.granted_bw)
             if task.reserved_mb:
                 # commit-at-finish: the written bytes become resident data;
                 # a failed writer's reservation is returned instead
@@ -847,8 +823,6 @@ class Scheduler:
             w.free_io_executors += 1
             dev = task.device or w.storage
             dev.release(task.granted_bw)
-            if self.shard_lease is not None and task.granted_bw > 0:
-                self.shard_lease.release(self.shard_id, dev, task.granted_bw)
             if task.reserved_mb:
                 dev.cancel_reservation(task.reserved_mb)
         if task.epoch is not None:

@@ -138,8 +138,7 @@ class TaskInstance:
         "device", "granted_bw", "tuner_key", "reserved_mb", "read_penalty",
         "_datalife", "submit_time", "start_time", "end_time",
         "measured_duration", "_telemetry_k", "epoch", "retries", "error",
-        "_ready_seq", "_sim_seq", "shard", "shard_key", "_plan_seq",
-        "_span")
+        "_ready_seq", "_sim_seq", "_plan_seq", "_span")
 
     def __init__(self, defn: TaskDef, args: tuple, kwargs: dict,
                  sim: SimSpec | None = None,
@@ -197,10 +196,6 @@ class TaskInstance:
         self.error: Optional[BaseException] = None
         self._ready_seq = -1                 # global readiness order (scheduler)
         self._sim_seq = -1                   # launch order (sim event queue)
-        # sharded control plane (core.shardplane): owning shard and the
-        # optional explicit routing anchor (``shard_key=`` call-time kwarg)
-        self.shard = 0
-        self.shard_key = None
 
     @property
     def duration(self) -> float:

@@ -222,37 +222,6 @@ def _io402():
     return rt, "entropy"
 
 
-@case("IO601", "ping-pongs across shards")
-def _io601():
-    @task(returns=1)
-    def hop(x):
-        pass
-
-    with IORuntime(tiered(), backend="capture") as rt:
-        r = hop(0, shard_key=0)
-        hop(r, shard_key=1)  # alternating anchors: every edge cross-shard
-    return rt, "hop"
-
-
-@case("IO602", "distinct workers")
-def _io602():
-    @constraint(tier="bb", storageBW=100)
-    @io
-    @task(returns=1)
-    def publish(i):
-        pass
-
-    @task(returns=1)
-    def read(x):
-        pass
-
-    with IORuntime(tiered(), backend="capture") as rt:
-        m = publish(0, io_mb=8.0)
-        read(m, shard_key=0)
-        read(m, shard_key=1)  # shared-tier output fanned across anchors
-    return rt, "publish"
-
-
 @pytest.mark.parametrize("code,substr,builder", CASES)
 def test_code_fires(code, substr, builder):
     rt, offender = builder()
@@ -270,7 +239,7 @@ def test_lint_categories_covered():
     cats = {p.values[0][2:3] for p in CASES}
     # category "5" (failure-domains) is exercised end-to-end in
     # test_failures.py against live fault-injection runs
-    assert cats == {"1", "2", "3", "4", "6"}
+    assert cats == {"1", "2", "3", "4"}
     assert len(CASES) >= 12  # distinct codes, each with a dedicated case
 
 
