@@ -2,19 +2,26 @@
 masks, full-sequence forward (train & prefill) and single-token decode
 against a (optionally rolling) KV cache.
 
-``multihead_attn`` takes one of four full-sequence paths:
+``multihead_attn`` takes one of four full-sequence paths, chosen by
+``attention_path`` from what the code can observe (backend, mask, shape);
+``use_flash`` forces the first:
 
-- ``use_flash``: the Pallas flash-attention kernel (kernels target real
-  TPUs and are validated separately in interpret mode);
-- otherwise, by shape and mask alone:
-  - ``S >= chunk_q_threshold`` (a multiple of ``chunk_q``):
-    ``_chunked_attn``, a scan over query chunks that bounds the score
-    temporary at long sequences;
-  - causal with ``S`` a multiple of ``CAUSAL_CHUNK`` and at least two
-    chunks: ``_causal_chunked_attn``, where each query chunk attends only
-    to the keys it can see, so the masked upper triangle is not computed;
-  - everything else (bidirectional, short sequences): ``_dense_attn``,
-    the lowering used by the dry-run/roofline.
+- ``"flash"``: the Pallas flash-attention kernels
+  (``kernels/flash_attention``), forward and backward, which keep the
+  scores in VMEM and skip the blocks the mask hides. Taken on the TPU for
+  causal masks (with or without a window) where ``S`` is a multiple of the
+  kernels' block and at least two of them, ``hd`` is 64 or 128, and no
+  multi-device mesh is current (XLA does not partition a Pallas call). On
+  the CPU it runs only when forced, in interpret mode.
+- ``"chunked"``: ``S >= chunk_q_threshold`` (a multiple of ``chunk_q``):
+  ``_chunked_attn``, a scan over query chunks that bounds the score
+  temporary at long sequences;
+- ``"causal_chunks"``: causal with ``S`` a multiple of ``CAUSAL_CHUNK``
+  and at least two chunks: ``_causal_chunked_attn``, where each query chunk
+  attends only to the keys it can see, so the masked upper triangle is not
+  computed;
+- ``"dense"``: everything else (bidirectional, short sequences):
+  ``_dense_attn``, the lowering used by the dry-run/roofline.
 """
 from __future__ import annotations
 
@@ -24,6 +31,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..distributed import current_mesh
+from ..kernels.flash_attention import ops as flash_ops
 from .layers import _init, apply_rope
 
 # Query chunk of the causal key-truncated path: chunk i attends to keys
@@ -130,9 +139,28 @@ def _chunked_attn(q, k, v, positions, causal, window, chunk_q):
     return jnp.moveaxis(outs, 0, 1).reshape(B, S, H, hd)
 
 
+FLASH_HEAD_DIMS = (64, 128)
+
+
+def attention_path(S, hd, causal, backend, *, mesh_devices=1,
+                   chunk_q_threshold=8192, chunk_q=1024) -> str:
+    """Which full-sequence path ``multihead_attn`` takes without
+    ``use_flash``: "flash", "chunked", "causal_chunks" or "dense". The
+    kernels mask a sliding window exactly as the XLA paths do, so the
+    window does not enter the choice."""
+    block = flash_ops.flash_block(S, hd)
+    if (backend == "tpu" and causal and mesh_devices == 1
+            and hd in FLASH_HEAD_DIMS and S % block == 0 and S >= 2 * block):
+        return "flash"
+    if S >= chunk_q_threshold and S % chunk_q == 0:
+        return "chunked"
+    if causal and S % CAUSAL_CHUNK == 0 and S >= 2 * CAUSAL_CHUNK:
+        return "causal_chunks"
+    return "dense"
+
+
 def multihead_attn(p, x, positions, *, causal=True, window=0, rope_theta=1e4,
-                   use_flash=False, flash_block=512, chunk_q_threshold=8192,
-                   chunk_q=1024):
+                   use_flash=False, chunk_q_threshold=8192, chunk_q=1024):
     """x: (B, S, D) -> (B, S, D)."""
     B, S, D = x.shape
     H, hd = p["q"].shape[1], p["q"].shape[2]
@@ -141,13 +169,16 @@ def multihead_attn(p, x, positions, *, causal=True, window=0, rope_theta=1e4,
     v = jnp.einsum("bsd,dhk->bshk", x, p["v"])
     q = apply_rope(q, positions, rope_theta)
     k = apply_rope(k, positions, rope_theta)
-    if use_flash:
-        from ..kernels.flash_attention import ops as flash_ops
-        o = flash_ops.flash_attention(q, k, v, causal=causal, window=window,
-                                      block_q=flash_block, block_k=flash_block)
-    elif S >= chunk_q_threshold and S % chunk_q == 0:
+    mesh = current_mesh()
+    path = "flash" if use_flash else attention_path(
+        S, hd, causal, jax.default_backend(),
+        mesh_devices=mesh.size if mesh is not None else 1,
+        chunk_q_threshold=chunk_q_threshold, chunk_q=chunk_q)
+    if path == "flash":
+        o = flash_ops.flash_attention(q, k, v, causal, window)
+    elif path == "chunked":
         o = _chunked_attn(q, k, v, positions, causal, window, chunk_q)
-    elif causal and S % CAUSAL_CHUNK == 0 and S >= 2 * CAUSAL_CHUNK:
+    elif path == "causal_chunks":
         o = _causal_chunked_attn(q, k, v, positions, window, CAUSAL_CHUNK)
     else:
         o = _dense_attn(q, k, v, positions, positions, causal, window)

@@ -142,3 +142,56 @@ def test_model_loss_and_grads_match_dense(monkeypatch):
     for gc, gd in zip(jax.tree.leaves(grads_c), jax.tree.leaves(grads_d)):
         np.testing.assert_allclose(np.asarray(gc), np.asarray(gd),
                                    atol=1e-5, rtol=1e-5)
+
+
+FLASH_BLOCK = attention.flash_ops.flash_block(2048, 64)
+
+
+@pytest.mark.parametrize("S,hd,causal,backend,mesh_devices,path", [
+    (2048, 64, True, "tpu", 1, "flash"),
+    (2048, 128, True, "tpu", 1, "flash"),
+    (2048, 64, True, "cpu", 1, "causal_chunks"),
+    (8192, 64, True, "cpu", 1, "chunked"),
+    (8192, 64, True, "tpu", 1, "flash"),
+    (2048, 64, False, "tpu", 1, "dense"),
+    (2048, 64, False, "cpu", 1, "dense"),
+    (256, 64, True, "tpu", 1, "flash"),       # two blocks of 128
+    (128, 64, True, "tpu", 1, "dense"),       # one block
+    (2 * FLASH_BLOCK + 256, 64, True, "tpu", 1, "causal_chunks"),
+    (2048 + 8, 64, True, "tpu", 1, "dense"),
+    (2048, 80, True, "tpu", 1, "causal_chunks"),
+    (2048, 64, True, "tpu", 4, "causal_chunks"),
+])
+def test_attention_path(S, hd, causal, backend, mesh_devices, path):
+    assert attention.attention_path(S, hd, causal, backend,
+                                    mesh_devices=mesh_devices) == path
+
+
+@pytest.mark.parametrize("window", [0, 96])
+def test_forced_flash_matches_chunked(window):
+    """``use_flash`` on the CPU (interpret mode) against the causal chunked
+    path it replaces on the TPU: output and gradients of the layer."""
+    S, D = 256, 64
+    p, _ = attention.attn_init(jax.random.PRNGKey(0), D, 6, 2, 32,
+                               jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, S, D), jnp.float32)
+    pos = jnp.broadcast_to(jnp.arange(S), (2, S))
+
+    def run(use_flash, chunk):
+        def f(p, x):
+            return multihead_attn(p, x, pos, causal=True, window=window,
+                                  use_flash=use_flash)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(attention, "CAUSAL_CHUNK", chunk)
+            with jax.default_matmul_precision("highest"):
+                out, vjp = jax.vjp(f, p, x)
+                ct = jax.random.normal(jax.random.PRNGKey(2), out.shape)
+                return out, vjp(ct)
+
+    out_f, grads_f = run(True, 64)
+    out_c, grads_c = run(False, 64)
+    np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_c),
+                               atol=1e-5, rtol=1e-5)
+    for gf, gc in zip(jax.tree.leaves(grads_f), jax.tree.leaves(grads_c)):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gc),
+                                   atol=1e-4, rtol=1e-4)

@@ -41,17 +41,32 @@ def test_flash_matches_ref(case, dtype, tol):
                                np.asarray(ref, np.float32), atol=tol, rtol=tol)
 
 
-def test_flash_grads_match_ref():
-    q = rnd(4, (1, 128, 4, 32), jnp.float32)
-    k = rnd(5, (1, 128, 2, 32), jnp.float32)
-    v = rnd(6, (1, 128, 2, 32), jnp.float32)
-    for argnum in range(3):
-        g1 = jax.grad(lambda *a: flash_attention(*a, True, 0, 64, 64).sum(),
-                      argnums=argnum)(q, k, v)
-        g2 = jax.grad(lambda *a: attention_ref(*a, causal=True).sum(),
-                      argnums=argnum)(q, k, v)
-        np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
-                                   atol=1e-5, rtol=1e-5)
+GRAD_CASES = FLASH_CASES + [
+    (1, 128, 4, 2, 32, True, 0, 64, 64),
+    # GQA with G = 3 at hd 64, SmolLM-360M's head layout
+    (1, 256, 6, 2, 64, True, 0, 64, 64),
+]
+
+
+@pytest.mark.parametrize("case", GRAD_CASES)
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 5e-2)])
+def test_flash_grads_match_ref(case, dtype, tol):
+    """dQ, dK and dV through the backward kernels against autodiff of the
+    oracle, for a random output cotangent."""
+    B, S, H, KV, hd, causal, win, bq, bk = case
+    q = rnd(4, (B, S, H, hd), dtype)
+    k = rnd(5, (B, S, KV, hd), dtype)
+    v = rnd(6, (B, S, KV, hd), dtype)
+    ct = rnd(7, (B, S, H, hd), dtype)
+    _, vjp = jax.vjp(lambda *a: flash_attention(*a, causal, win, bq, bk),
+                     q, k, v)
+    _, vjp_ref = jax.vjp(
+        lambda *a: attention_ref(*a, causal=causal, window=win), q, k, v)
+    for name, g1, g2 in zip(("dq", "dk", "dv"), vjp(ct), vjp_ref(ct)):
+        assert g1.dtype == dtype, name
+        np.testing.assert_allclose(np.asarray(g1, np.float32),
+                                   np.asarray(g2, np.float32),
+                                   atol=tol, rtol=tol, err_msg=name)
 
 
 SSD_CASES = [

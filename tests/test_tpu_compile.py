@@ -18,7 +18,9 @@ from jax.sharding import SingleDeviceSharding
 from chip_smoke import (SERVE_BATCH, SERVE_NEW, SERVE_PROMPT, TRAIN_BATCH,
                         TRAIN_SEQ)
 from repro.configs import get_config
-from repro.kernels.flash_attention.kernel import flash_attention_fwd
+from repro.kernels.flash_attention import ops as flash_ops
+from repro.kernels.flash_attention.kernel import (flash_attention_bwd,
+                                                  flash_attention_fwd)
 from repro.kernels.ssd_scan.kernel import ssd_scan_fwd
 from repro.launch.train import make_train_step
 from repro.models import Model
@@ -63,8 +65,62 @@ def test_flash_attention_compiles_at_tinyllama_widths(one_chip, window):
     q = _sds(one_chip, (B, S, H, hd))
     kv = _sds(one_chip, (B, S, KV, hd))
     compiled = jax.jit(lambda q, k, v: flash_attention_fwd(
-        q, k, v, causal=True, window=window)).lower(q, kv, kv).compile()
+        q, k, v, causal=True, window=window)[0]).lower(q, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# SmolLM-360M's training shape: B 4, S 2048, H 15, KV 5, hd 64, float32
+SMOLLM_ATTN = (4, 2048, 15, 5, 64)
+
+
+@pytest.mark.parametrize("kernel", ["flash_attn_fwd", "flash_attn_dq",
+                                    "flash_attn_dkdv"])
+def test_flash_kernels_compile_at_smollm_widths(one_chip, kernel):
+    """The forward with its log-sum-exp and both backward kernels, at the
+    blocks the shape chooses, with bfloat16 operands on the MXU."""
+    B, S, H, KV, hd = SMOLLM_ATTN
+    f32 = jnp.float32
+    q, o, do = (_sds(one_chip, (B, S, H, hd), f32) for _ in range(3))
+    k, v = (_sds(one_chip, (B, S, KV, hd), f32) for _ in range(2))
+    lse = _sds(one_chip, (B, H, S), f32)
+    blocks = flash_ops.blocks_for(S, hd)
+    kw = dict(causal=True, mxu_dtype=jnp.bfloat16)
+    if kernel == "flash_attn_fwd":
+        bq, bk = blocks["fwd"]
+        fn = jax.jit(lambda q, k, v: flash_attention_fwd(
+            q, k, v, block_q=bq, block_k=bk, **kw))
+        args = (q, k, v)
+    else:
+        pick = (lambda r: r[0]) if kernel == "flash_attn_dq" \
+            else (lambda r: r[1:])
+        fn = jax.jit(lambda *a: pick(flash_attention_bwd(
+            *a, dq_blocks=blocks["dq"], dkdv_blocks=blocks["dkdv"], **kw)))
+        args = (q, k, v, o, lse, do)
+    hlo = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo and kernel in hlo
+
+
+def test_smollm_train_step_with_flash_fits_one_chip(one_chip, monkeypatch):
+    """SmolLM-360M's float32 train step at the cells' 4 x 2048, through the
+    flash kernels as the TPU takes them: compiled for the chip (not in
+    interpret mode), and within its memory."""
+    monkeypatch.setattr(flash_ops, "_on_cpu", lambda: False)
+    cfg = get_config("smollm-360m").replace(dtype=jnp.float32, remat=True,
+                                            use_flash=True)
+    model = Model(cfg)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0))[0]))
+    opt_state = _on(one_chip, jax.eval_shape(adamw_init, params))
+    batch = {k: _sds(one_chip, (4, 2048), jnp.int32)
+             for k in ("tokens", "targets")}
+    compiled = make_train_step(model, AdamWConfig()).lower(
+        params, opt_state, batch).compile()
+    hlo = compiled.as_text()
+    for kernel in ("flash_attn_fwd", "flash_attn_dq", "flash_attn_dkdv"):
+        assert kernel in hlo, kernel
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        < V5E_HBM_BYTES, mem
 
 
 def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
